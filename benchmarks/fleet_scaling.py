@@ -48,6 +48,7 @@ import jax
 from benchmarks.common import emit, make_session
 from repro import api
 from repro.fleet import CloudBatcherConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving import engine as engine_lib
 from repro.serving import tape as tape_lib
 
@@ -202,6 +203,7 @@ def main(argv=None):
     ap.add_argument("--csv", default=None,
                     help="append sharded-grid rows to this CSV")
     args = ap.parse_args(argv)
+    use_compile_cache()
     print("name,value,derived")
     if args.sharded:
         sharded_grid(s_list=tuple(args.s_list or SHARD_S_LIST),

@@ -23,6 +23,7 @@ from benchmarks.common import emit, small_scene, timed
 from repro import ops
 from repro.core import projection, transform
 from repro.data import scenes
+from repro.launch.compile_cache import use_compile_cache
 from repro.runtime import profiles
 from repro.serving.common import nominal_transform_time
 
@@ -150,4 +151,5 @@ def run():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run()

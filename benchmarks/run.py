@@ -17,6 +17,7 @@ import argparse
 import time
 
 from benchmarks import common
+from repro.launch.compile_cache import use_compile_cache
 
 MODULES = [
     "fig2_edge_only",
@@ -63,6 +64,7 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown module(s) {', '.join(unknown)}; available: "
                  f"{', '.join(MODULES)}")
+    use_compile_cache()
     common.set_defaults(args.scenario, args.policy)
     common.set_obs(common.obs_from_args(args))
 

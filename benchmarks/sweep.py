@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from benchmarks.common import emit
 from repro import api
+from repro.launch.compile_cache import use_compile_cache
 
 # Default grid: the scenario-diversity presets crossed against the paper
 # policy, two periodic baselines bracketing its offload rate, and the
@@ -112,6 +113,7 @@ def main() -> None:
     add_obs_args(ap)
     args = ap.parse_args()
     obs = obs_from_args(args)
+    use_compile_cache()
     print("name,value,derived")
     if args.smoke:
         text, _ = sweep(scenarios=("smoke",), policies=("fos", "adaptive"),

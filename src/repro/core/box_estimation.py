@@ -110,7 +110,8 @@ def center_from_extents(points_xy: jnp.ndarray, mask: jnp.ndarray,
     n = jnp.maximum(jnp.sum(mask), 1)
 
     def axis_center(axis_vec, extent):
-        c = points_xy @ axis_vec                         # (P,)
+        c = jnp.matmul(points_xy, axis_vec,
+                       precision=jax.lax.Precision.HIGHEST)  # (P,)
         lo = jnp.min(jnp.where(mask, c, 1e9))
         hi = jnp.max(jnp.where(mask, c, -1e9))
         origin = 0.0  # sensor at the LiDAR origin

@@ -188,9 +188,9 @@ def project_box3d_to_2d(box: jnp.ndarray, tr: jnp.ndarray,
     """
     corners = corners_3d(box)  # (8, 3)
     hom = jnp.concatenate([corners, jnp.ones((8, 1), dtype=corners.dtype)], axis=-1)
-    cam = hom @ tr.T           # (8, 3)
+    cam = jnp.matmul(hom, tr.T, precision=jax.lax.Precision.HIGHEST)  # (8, 3)
     cam_h = jnp.concatenate([cam, jnp.ones((8, 1), dtype=cam.dtype)], axis=-1)
-    uvw = cam_h @ P.T          # (8, 3)
+    uvw = jnp.matmul(cam_h, P.T, precision=jax.lax.Precision.HIGHEST)  # (8, 3)
     w = jnp.where(jnp.abs(uvw[:, 2]) < 1e-6, 1e-6, uvw[:, 2])
     u = uvw[:, 0] / w
     v = uvw[:, 1] / w

@@ -107,7 +107,9 @@ def ransac_planes(key: jax.Array, points: jnp.ndarray, valid: jnp.ndarray,
     best = jnp.argmax(counts, axis=1)
     n_best = jnp.take_along_axis(normals, best[:, None, None], axis=1)[:, 0]
     d_best = jnp.take_along_axis(offsets, best[:, None], axis=1)[:, 0]
-    dist = jnp.abs(jnp.einsum("opc,oc->op", points, n_best) + d_best[:, None])
+    dist = jnp.abs(jnp.einsum("opc,oc->op", points, n_best,
+                              precision=jax.lax.Precision.HIGHEST)
+                   + d_best[:, None])
     inliers = (dist < params.inlier_thresh) & valid
     num = jnp.take_along_axis(counts, best[:, None], axis=1)[:, 0]
     ok = num >= 3

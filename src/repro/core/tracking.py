@@ -16,6 +16,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+# f32 matmuls at full precision: a TPU's default is one bf16 pass.
+_HI = jax.lax.Precision.HIGHEST
+
 STATE_DIM = 7
 OBS_DIM = 4
 
@@ -88,11 +91,11 @@ def predict(state: TrackState) -> tuple[TrackState, jnp.ndarray]:
     """Kalman predict for all active slots. Returns predicted 2D boxes (T, 4)."""
     f, _ = _fh_matrices(state.x.dtype)
     q, _ = _qr_matrices(state.x.dtype)
-    x = state.x @ f.T
+    x = jnp.matmul(state.x, f.T, precision=_HI)
     # Clamp scale velocity so area stays positive (SORT convention).
     neg = (x[:, 2] + x[:, 6]) <= 0
     x = x.at[:, 6].set(jnp.where(neg, 0.0, x[:, 6]))
-    p = jnp.einsum('ij,tjk,lk->til', f, state.p, f) + q[None]
+    p = jnp.einsum('ij,tjk,lk->til', f, state.p, f, precision=_HI) + q[None]
     x = jnp.where(state.active[:, None], x, state.x)
     p = jnp.where(state.active[:, None, None], p, state.p)
     boxes = z_to_bbox(x[:, :4])
@@ -114,11 +117,13 @@ def update(state: TrackState, track_to_det: jnp.ndarray, det_boxes: jnp.ndarray,
     z = bbox_to_z(det_boxes[det_idx])  # (T, 4)
 
     def kupdate(x, p, zi):
-        y = zi - h @ x
-        s = h @ p @ h.T + r
-        k = jnp.linalg.solve(s, h @ p).T  # (7, 4)
-        x2 = x + k @ y
-        p2 = (jnp.eye(STATE_DIM, dtype=x.dtype) - k @ h) @ p
+        hp = jnp.matmul(h, p, precision=_HI)
+        y = zi - jnp.matmul(h, x, precision=_HI)
+        s = jnp.matmul(hp, h.T, precision=_HI) + r
+        k = jnp.linalg.solve(s, hp).T  # (7, 4)
+        x2 = x + jnp.matmul(k, y, precision=_HI)
+        p2 = jnp.matmul(jnp.eye(STATE_DIM, dtype=x.dtype)
+                        - jnp.matmul(k, h, precision=_HI), p, precision=_HI)
         return x2, p2
 
     x2, p2 = jax.vmap(kupdate)(state.x, state.p, z)

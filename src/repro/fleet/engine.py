@@ -214,15 +214,17 @@ class FleetEngine:
                                             offload_cost_s=off)
         return state._replace(sched=sched)
 
+    def _put(self, a: np.ndarray, spec: P) -> jax.Array:
+        """Host array -> device. Under a mesh it goes straight into its
+        ``spec`` shards instead of landing whole on device 0 first."""
+        if self.mesh is None:
+            return jnp.asarray(a)
+        return jax.device_put(a, NamedSharding(self.mesh, spec))
+
     def _frame_inputs(self, stack: tape_lib.FrameTape,
                       t: int) -> step_lib.FrameInputs:
-        f = tape_lib.FrameTape(*(a[:, t] for a in stack))
         return step_lib.FrameInputs(
-            points=jnp.asarray(f.points), det2d=jnp.asarray(f.det2d),
-            val2d=jnp.asarray(f.val2d), label_img=jnp.asarray(f.label_img),
-            det3d=jnp.asarray(f.det3d), val3d=jnp.asarray(f.val3d),
-            gt_boxes=jnp.asarray(f.gt_boxes),
-            gt_visible=jnp.asarray(f.gt_visible))
+            *(self._put(a[:, t], P("streams")) for a in stack))
 
     # ------------------------------------------------------------------
     def run(self, n_frames: int) -> RunReport:
@@ -258,8 +260,9 @@ class FleetEngine:
             with obs.measured_span("fleet/dispatch", jit_fn=self._step,
                                    frame=t) if obs is not None \
                     else _NULL_CTX:
-                state, packed = self._step(state, inp, jnp.asarray(arrived),
-                                           jnp.int32(t))
+                state, packed = self._step(
+                    state, inp, self._put(arrived, P("streams")),
+                    jnp.int32(t))
             with obs.measured_span("fleet/fetch", frame=t) \
                     if obs is not None else _NULL_CTX:
                 pk = np.asarray(packed)        # the one fetch per frame
@@ -357,12 +360,12 @@ class FleetEngine:
                 "ObsConfig(audit=...) requires the orchestrated "
                 "FleetEngine.run(); scan mode keeps the scheduler "
                 "telemetry on device")
-        fn = self._scan_fn()
+        fn, consts = self._scan_fn()
         with obs.measured_span("fleet/scan_dispatch", jit_fn=fn,
                                n_frames=n_frames) if obs is not None \
                 else _NULL_CTX:
-            state, outs = fn(
-                self._init_state(), self._scan_inputs(n_frames), n_frames)
+            state, outs = fn(consts, self._init_state(),
+                             self._scan_inputs(n_frames), n_frames)
         with obs.measured_span("fleet/scan_fetch") if obs is not None \
                 else _NULL_CTX:
             packed = np.asarray(outs).transpose(1, 0, 2)  # (F,S,C)->(S,F,C)
@@ -377,18 +380,9 @@ class FleetEngine:
         # (S, F, ...) -> (F, S, ...) device arrays for scan's leading axis;
         # under a mesh the tape lands stream-sharded (axis 1) up front, so
         # the scan dispatch never re-lays-out the largest buffers.
-        put = jnp.asarray if self.mesh is None else (
-            lambda a: jax.device_put(
-                a, NamedSharding(self.mesh, P(None, "streams"))))
         return step_lib.FrameInputs(
-            points=put(stack.points.swapaxes(0, 1)),
-            det2d=put(stack.det2d.swapaxes(0, 1)),
-            val2d=put(stack.val2d.swapaxes(0, 1)),
-            label_img=put(stack.label_img.swapaxes(0, 1)),
-            det3d=put(stack.det3d.swapaxes(0, 1)),
-            val3d=put(stack.val3d.swapaxes(0, 1)),
-            gt_boxes=put(stack.gt_boxes.swapaxes(0, 1)),
-            gt_visible=put(stack.gt_visible.swapaxes(0, 1)))
+            *(self._put(a.swapaxes(0, 1), P(None, "streams"))
+              for a in stack))
 
     def _scan_fn(self):
         if self._scan_cache is not None:

@@ -17,12 +17,11 @@ string resolved through the ops registry — so the whole vmapped fleet
 jits cleanly under either the ref or the Pallas backend.
 
 **Sharded megafleet** (``mesh=``): both builders accept a 1-D ``streams``
-device mesh (``launch.mesh.make_fleet_mesh``). The per-frame step shards
-every (S, ...) carry/input buffer along it with ``NamedSharding`` on the
-jit boundary plus ``models.sharding.constrain`` logical-rule hooks on the
-carry outputs; the scan twin runs per shard under ``shard_map`` with a
-cross-shard ``psum`` of the round's sender count — the one scalar that
-couples streams — so the shared-uplink byte total / bandwidth shares and
+device mesh (``launch.mesh.make_fleet_mesh``). Both shard every
+(S, ...) carry/input buffer along it and run per shard under
+``jax.shard_map``; the scan twin adds a cross-shard ``psum`` of the
+round's sender count — the one scalar that couples streams — so the
+shared-uplink byte total / bandwidth shares and
 the cloud GPU-pool queue depth stay *globally* consistent, not
 per-shard-local. The replicated pool state (busy clocks, round-robin
 pointer) is then recomputed identically on every shard. On a 1-device
@@ -43,11 +42,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import metrics, scheduler, transform
-from repro.models import sharding as sharding_lib
 from repro.serving.common import ComponentTimes, nominal_transform_time
-
-# Logical -> mesh axis rules for the fleet path (models.sharding.constrain).
-FLEET_RULES = {"streams": "streams"}
 
 # Columns of the packed per-stream stats row (the one host fetch per frame).
 COL_IS_ANCHOR = 0
@@ -145,45 +140,28 @@ def _stream_step(state: FleetState, inp: FrameInputs,
     return FleetState(mstate, sched_state, new_ib, new_iv), packed
 
 
-def _constrain_streams(tree):
-    """Pin every (S, ...) leaf to the ``streams`` logical axis (identity
-    outside an installed rules context). Extended dtypes (PRNG key arrays)
-    are skipped — their placement rides on the jit boundary shardings."""
-    def one(x):
-        if jnp.issubdtype(x.dtype, jax.dtypes.extended):
-            return x
-        return sharding_lib.constrain(
-            x, ("streams",) + (None,) * (x.ndim - 1))
-    return jax.tree.map(one, tree)
-
-
 def make_fleet_step(calib, params, sparams, use_fos: bool = True,
                     mesh=None):
     """Jitted (state, FrameInputs[S], test_arrived[S], t) -> (state, (S, N_COLS)).
 
     The carry (arg 0) is donated: per-frame stepping reuses the state
     buffers in place. With ``mesh`` (a 1-D ``streams`` mesh) every
-    (S, ...) buffer is sharded along the stream axis — explicit
-    ``NamedSharding`` on the jit boundary, ``models.sharding.constrain``
-    rules on the carry outputs. The step has no cross-stream math, so the
-    partitioned dispatch is embarrassingly parallel (contention stays on
-    the host, which already computes it globally)."""
+    (S, ...) input and output is sharded along the stream axis, and each
+    shard steps its own streams under ``jax.shard_map`` (XLA cannot
+    partition a Pallas kernel by itself). The step has no cross-stream
+    math, so the partitioned dispatch is embarrassingly parallel
+    (contention stays on the host, which already computes it globally)."""
     step = functools.partial(_stream_step, calib=calib, params=params,
                              sparams=sparams, use_fos=use_fos)
     vstep = jax.vmap(step, in_axes=(0, 0, 0, None))
     if mesh is None:
         return jax.jit(vstep, donate_argnums=(0,))
-    s_sh = NamedSharding(mesh, P("streams"))
+    s = P("streams")
+    vstep = jax.shard_map(vstep, mesh=mesh, in_specs=(s, s, s, P()),
+                          out_specs=(s, s), check_vma=False)
+    s_sh = NamedSharding(mesh, s)
     r_sh = NamedSharding(mesh, P())
-
-    def fleet_step(state, inp, test_arrived, t):
-        with sharding_lib.activation_rules(FLEET_RULES, mesh=mesh):
-            new_state, packed = vstep(state, inp, test_arrived, t)
-            new_state = _constrain_streams(new_state)
-            packed = sharding_lib.constrain(packed, ("streams", None))
-        return new_state, packed
-
-    return jax.jit(fleet_step, donate_argnums=(0,),
+    return jax.jit(vstep, donate_argnums=(0,),
                    in_shardings=(s_sh, s_sh, s_sh, r_sh),
                    out_shardings=(s_sh, s_sh))
 
@@ -254,8 +232,13 @@ def make_fleet_scan(n_streams: int, calib, params, sparams,
                     use_fos: bool = True, onboard_anchors: bool = False,
                     edge_infer_s: float = 0.0,
                     charge_fos: bool = None, mesh=None):
-    """Jitted (state, FrameInputs stacked (F, S, ...), n_frames) ->
-    (state, (F, S, N_COLS + 2)) — a whole fleet run in one dispatch.
+    """A whole fleet run in one dispatch. Returns ``(fn, consts)``: the
+    jitted ``fn(consts, state, FrameInputs stacked (F, S, ...), n_frames)
+    -> (state, (F, S, N_COLS + 2))`` and its :class:`ScanConsts`.
+
+    The constants are an operand, never a closure: XLA would fold closed-
+    over constants into the unsharded program's arithmetic but not into
+    the per-shard one, and the two would then differ in the last bit.
 
     ``onboard_anchors`` mirrors the engine's ``moby_onboard`` mode: anchor
     frames run the 3D detector on the edge (``edge_infer_s``) and do not
@@ -265,13 +248,13 @@ def make_fleet_scan(n_streams: int, calib, params, sparams,
     that never offload test frames).
 
     ``mesh`` (a 1-D ``streams`` mesh) runs the scan per shard under
-    ``shard_map``: every (S, ...) carry/tape buffer is partitioned along
+    ``jax.shard_map``: every (S, ...) carry/tape buffer is partitioned along
     the stream axis, while the round's sender count — the single scalar
     coupling streams through the shared uplink (byte total, bandwidth
     shares) and the cloud GPU pool (queue depth) — is ``psum``-ed across
     shards, so the contention model stays globally consistent and every
-    shard recomputes identical replicated pool clocks. The carry (arg 0)
-    is donated in both variants.
+    shard recomputes identical replicated pool clocks. The carry
+    (``state``) is donated in both variants.
     """
     if charge_fos is None:
         charge_fos = use_fos
@@ -281,9 +264,8 @@ def make_fleet_scan(n_streams: int, calib, params, sparams,
     axis = "streams" if mesh is not None else None
 
     # Per-run constants: host-f64 component sums rounded to f32 once, in
-    # the same order the old closure path rounded them (bitwise contract
-    # with the pre-mesh implementation and with the host engine's uniform
-    # -fleet parity; see ScanConsts).
+    # the same order the host engine rounds them (bitwise contract with
+    # the host engine's uniform-fleet parity; see ScanConsts).
     def svec(v):
         return jnp.asarray(
             np.broadcast_to(np.asarray(v, np.float64), (n_streams,)),
@@ -420,21 +402,20 @@ def make_fleet_scan(n_streams: int, calib, params, sparams,
     if mesh is None:
         core = scan_core
     else:
-        from jax.experimental.shard_map import shard_map
         s = P("streams")
         cs_specs = ScanConsts(bw_trace=P(), edge_cost_s=s, edge_infer_s=s,
                               ob_base=s, ob_new=s, ob_assoc=s,
                               ob_tba=s, ob_fos=s)
-        core = shard_map(
+        core = jax.shard_map(
             scan_core, mesh=mesh,
             in_specs=(cs_specs, s, s, s, P(), P(), P(), P(None, "streams")),
             out_specs=(s, P(None, "streams")),
-            check_rep=False)
+            check_vma=False)
 
-    def run(state, stacked: FrameInputs, n_frames: int):
+    def run(cs: ScanConsts, state, stacked: FrameInputs, n_frames: int):
         busy0 = jnp.float32(0.0) if net.n_gpus == 1 \
             else jnp.zeros((net.n_gpus,), jnp.float32)
-        return core(consts, state,
+        return core(cs, state,
                     jnp.zeros((n_streams,), jnp.float32),
                     jnp.full((n_streams,), jnp.inf, jnp.float32),
                     busy0,
@@ -442,4 +423,5 @@ def make_fleet_scan(n_streams: int, calib, params, sparams,
                     jnp.arange(n_frames, dtype=jnp.int32),
                     stacked)
 
-    return jax.jit(run, static_argnames=("n_frames",), donate_argnums=(0,))
+    fn = jax.jit(run, static_argnames=("n_frames",), donate_argnums=(1,))
+    return fn, consts

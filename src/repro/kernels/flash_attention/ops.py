@@ -8,13 +8,16 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention.flash_attention import (TK, TQ,
                                                            flash_attention_pallas)
+from repro.ops import registry
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    causal: bool = True, interpret: bool = True
+                    causal: bool = True, interpret: bool | None = None
                     ) -> jnp.ndarray:
     """q: (B, H, SQ, hd); k/v: (B, KV, SK, hd) -> (B, H, SQ, hd)."""
+    if interpret is None:  # platform default: compiled on a TPU
+        interpret = registry.default_interpret()
     b, h, sq, hd = q.shape
     kv, sk = k.shape[1], k.shape[2]
     pq = (-sq) % TQ

@@ -1,6 +1,7 @@
 """Pure-jnp oracle for fused point projection."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -18,9 +19,9 @@ def point_proj_ref(points: jnp.ndarray, tr: jnp.ndarray, p: jnp.ndarray,
     """
     n = points.shape[0]
     hom = jnp.concatenate([points, jnp.ones((n, 1), points.dtype)], axis=-1)
-    cam = hom @ tr.T
+    cam = jnp.matmul(hom, tr.T, precision=jax.lax.Precision.HIGHEST)
     camh = jnp.concatenate([cam, jnp.ones((n, 1), points.dtype)], axis=-1)
-    pix = camh @ p.T
+    pix = jnp.matmul(camh, p.T, precision=jax.lax.Precision.HIGHEST)
     depth = pix[:, 2]
     w = jnp.where(jnp.abs(depth) < 1e-6, 1e-6, depth)
     uv = pix[:, :2] / w[:, None]

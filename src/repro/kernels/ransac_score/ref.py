@@ -1,6 +1,7 @@
 """Pure-jnp oracle for RANSAC plane-hypothesis inlier counting."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -19,7 +20,8 @@ def ransac_score_ref(points: jnp.ndarray, valid: jnp.ndarray,
     Returns:
       (O, K) int32 inlier counts.
     """
-    dist = jnp.abs(jnp.einsum("opc,okc->opk", points, normals)
+    dist = jnp.abs(jnp.einsum("opc,okc->opk", points, normals,
+                              precision=jax.lax.Precision.HIGHEST)
                    + offsets[:, None, :])
     inl = (dist < thresh) & valid[:, :, None]
     return jnp.sum(inl, axis=1).astype(jnp.int32)

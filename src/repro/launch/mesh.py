@@ -9,24 +9,33 @@ Fleet serving (repro.fleet) shards its stream axis over a 1-D ``streams``
 mesh built by :func:`make_fleet_mesh`; :func:`resolve_fleet_mesh` is the
 engine-facing resolver turning the user-facing spec (``None`` / ``"auto"``
 / a device count / a ready Mesh) into a mesh whose size divides the fleet.
+
+Every mesh built here has Auto axes: ``jax.make_mesh`` defaults to
+Explicit axes, which ``with_sharding_constraint`` (``models.sharding``)
+refuses.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh for CPU tests (uses however many host devices exist)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_fleet_mesh(n_devices: Optional[int] = None):
@@ -44,7 +53,7 @@ def make_fleet_mesh(n_devices: Optional[int] = None):
             f"make_fleet_mesh: asked for {n} devices, host has {avail} "
             f"(set XLA_FLAGS=--xla_force_host_platform_device_count "
             f"before JAX initializes to virtualize a CPU host)")
-    return jax.make_mesh((n,), ("streams",))
+    return _auto_mesh((n,), ("streams",))
 
 
 def fleet_shard_count(n_streams: int, n_devices: Optional[int] = None) -> int:

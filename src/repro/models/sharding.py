@@ -8,8 +8,8 @@ single device (tests, smoke runs).
 The rules context optionally carries the mesh itself: with a mesh
 installed, :func:`constrain` emits a fully explicit ``NamedSharding``
 constraint (the stable ``jax.sharding`` surface, usable outside any
-ambient mesh context) — this is how the fleet serving path
-(``repro.fleet.step``) pins its stream-sharded carry buffers.
+ambient mesh context). The mesh needs Auto axes, as ``launch.mesh``
+builds them: ``with_sharding_constraint`` refuses Explicit ones.
 """
 from __future__ import annotations
 

@@ -11,9 +11,9 @@ Backend resolution (see :mod:`repro.ops.registry`): explicit argument >
 ``MOBY_BACKEND`` env var > platform default (pallas on TPU, ref
 elsewhere). ``"auto"`` resolves per *op* from the startup
 micro-benchmark table (:mod:`repro.ops.autotune`) — the measured-fastest
-implementation per op on this host. The pallas implementations fall back
-to ``interpret=True`` automatically when no TPU is attached, so both
-backends are runnable — and parity-testable — on any host.
+implementation per op on this host. The pallas implementations run in
+interpret mode when no TPU is attached, so both backends are runnable —
+and parity-testable — on any host.
 """
 from repro.ops.api import (decode_attention, flash_attention, iou2d,
                            label_points, pillar_scatter, point_proj,

@@ -2,7 +2,7 @@
 
 Each function resolves its backend through :mod:`repro.ops.registry` and
 dispatches to either the pure-jnp reference or the Pallas kernel wrapper
-(with the interpret switch handled automatically off-TPU). These are the
+(whose interpret switch defaults from the platform). These are the
 ONLY sanctioned call sites for ``repro.kernels.*.ops`` outside tests —
 consumers (core, models, serving, fleet, benchmarks) import from here.
 """
@@ -28,10 +28,6 @@ from repro.kernels.ransac_score import ref as _rs_ref
 from repro.ops import registry
 
 
-def _interp() -> bool:
-    return registry.default_interpret()
-
-
 # ---------------------------------------------------------------------------
 # Differentiable pallas wrappers. ``pl.pallas_call`` has no VJP rule, but
 # training paths (LM/detector train steps) differentiate through attention
@@ -43,8 +39,7 @@ def _interp() -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _diff_flash(q, k, v, causal):
-    return _fa_ops.flash_attention(q, k, v, causal=causal,
-                                   interpret=_interp())
+    return _fa_ops.flash_attention(q, k, v, causal=causal)
 
 
 def _flash_fwd(q, k, v, causal):
@@ -77,7 +72,7 @@ _diff_flash.defvjp(_flash_fwd, _flash_bwd)
 
 @jax.custom_vjp
 def _diff_decode(q, ck, cv, pos):
-    return _dec_ops.decode_attention(q, ck, cv, pos, interpret=_interp())
+    return _dec_ops.decode_attention(q, ck, cv, pos)
 
 
 def _decode_fwd(q, ck, cv, pos):
@@ -95,7 +90,7 @@ _diff_decode.defvjp(_decode_fwd, _decode_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _diff_pillar_scatter(f, idx, val, g):
-    return _ps_ops.pillar_scatter(f, idx, val, g, interpret=_interp())
+    return _ps_ops.pillar_scatter(f, idx, val, g)
 
 
 def _scatter_fwd(f, idx, val, g):
@@ -113,27 +108,25 @@ _diff_pillar_scatter.defvjp(_scatter_fwd, _scatter_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Registrations: name -> (ref, pallas). The pallas side closes over the
-# interpret switch at call time so a TPU host compiles for real.
+# Registrations: name -> (ref, pallas).
 # ---------------------------------------------------------------------------
 
 registry.register_op(
     "point_proj",
     ref=lambda pts, tr, p, h, w: _pp_ref.point_proj_ref(pts, tr, p, h, w),
-    pallas=lambda pts, tr, p, h, w: _pp_ops.point_proj(
-        pts, tr, p, h, w, interpret=_interp()))
+    pallas=lambda pts, tr, p, h, w: _pp_ops.point_proj(pts, tr, p, h, w))
 
 registry.register_op(
     "iou2d",
     ref=lambda a, b: _iou_ref.iou2d_ref(a, b),
-    pallas=lambda a, b: _iou_ops.iou2d(a, b, interpret=_interp()))
+    pallas=lambda a, b: _iou_ops.iou2d(a, b))
 
 registry.register_op(
     "ransac_score",
     ref=lambda pts, val, nrm, off, th: _rs_ref.ransac_score_ref(
         pts, val, nrm, off, th),
     pallas=lambda pts, val, nrm, off, th: _rs_ops.ransac_score(
-        pts, val, nrm, off, float(th), interpret=_interp()))
+        pts, val, nrm, off, float(th)))
 
 registry.register_op(
     "pillar_scatter",

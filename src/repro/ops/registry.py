@@ -6,8 +6,9 @@ implementation per backend:
 
 * ``ref``    — pure-jnp reference (the oracle the kernels are tested
   against; also the fastest path on CPU hosts).
-* ``pallas`` — the Pallas TPU kernel. Off-TPU the kernel body runs in
-  interpret mode automatically, so the pallas path is *correct*
+* ``pallas`` — the Pallas TPU kernel. The kernel wrappers default their
+  ``interpret`` switch from :func:`default_interpret`: compiled by Mosaic
+  on a TPU, interpreted elsewhere, so the pallas path is *correct*
   everywhere and *fast* on TPU.
 
 Resolution order for the active backend:
@@ -41,10 +42,7 @@ _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 @functools.lru_cache(maxsize=1)
 def on_tpu() -> bool:
     import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # no backend at all (docs builds etc.)
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def default_interpret() -> bool:

@@ -114,7 +114,7 @@ class TestActivationRules:
     def test_constrain_values_unchanged_under_mesh(self):
         """With rules + mesh installed the constraint is semantically the
         identity on values (it only pins placement)."""
-        m = jax.make_mesh((1,), ("streams",))
+        m = mesh_lib.make_fleet_mesh(1)
         x = np.arange(12, dtype=np.float32).reshape(4, 3)
 
         def f(a):
@@ -127,7 +127,7 @@ class TestActivationRules:
         """The logical->mesh axis mapping lands in the traced constraint
         (XLA normalizes a 1-device sharding away post-compile, so check
         the jaxpr, not the output)."""
-        m = jax.make_mesh((1,), ("streams",))
+        m = mesh_lib.make_fleet_mesh(1)
 
         def f(a):
             with sharding.activation_rules({"streams": "streams"}, mesh=m):

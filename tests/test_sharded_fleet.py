@@ -90,8 +90,8 @@ class TestDonation:
     def test_scan_donates_carry(self):
         e = _engine(1)
         st = e._init_state()
-        fn = e._scan_fn()
-        hlo = fn.lower(st, e._scan_inputs(4), 4).compile().as_text()
+        fn, consts = e._scan_fn()
+        hlo = fn.lower(consts, st, e._scan_inputs(4), 4).compile().as_text()
         donated = hlo_analysis.donated_params(hlo)
         n_carry = len(jax.tree.leaves(st))
         assert len(donated) >= n_carry, sorted(donated)
