@@ -1,0 +1,19 @@
+"""``ransac_score``'s share of its roofline, in %: the least time for
+scoring every hypothesis of every cluster at the round's shapes
+(memory-bound at these shapes) over the kernel's device time per traced
+round."""
+from bench import roofline
+
+
+def read(ctx):
+    names = ctx.get("kernel_ops", {}).get("ransac_score", ())
+    ops = ctx["trace"]["ops"]
+    t = sum(ops[n][1] for n in names if n in ops) / ctx["rounds_traced"]
+    if not t:
+        return None
+    sh = ctx["shapes"]
+    least, _ = roofline.min_seconds(
+        roofline.ransac_score_work(sh["streams"], sh["max_obj"],
+                                   sh["pts_per_obj"], sh["ransac_iters"]),
+        ctx["device_kind"])
+    return 100.0 * least / t
