@@ -26,6 +26,18 @@ from repro.core import association, box_estimation, boxes as box_ops
 from repro.core import filtration, projection, ransac, tracking
 
 
+# Names of the device step's stages (``jax.named_scope``): each lands in the
+# ``op_name`` metadata of the stage's HLO instructions, so a profiler trace
+# of the compiled step can be read per stage. An anchor frame's ingest runs
+# under the same names as a transformed frame's matching work.
+STAGE_ASSOCIATE = "associate"
+STAGE_PROJECT = "project"
+STAGE_CLUSTERS = "clusters"
+STAGE_FILTRATION = "filtration"
+STAGE_RANSAC = "ransac"
+STAGE_BOXES = "boxes"
+
+
 class TransformParams(NamedTuple):
     filtration: filtration.FiltrationParams = filtration.FiltrationParams()
     ransac: ransac.RansacParams = ransac.RansacParams()
@@ -80,19 +92,22 @@ def anchor_step(state: MobyState, boxes3d: jnp.ndarray, valid: jnp.ndarray,
                 calib: projection.Calibration,
                 params: TransformParams = TransformParams()) -> tuple[MobyState, FrameOutput]:
     """Ingest cloud 3D detections at an anchor frame (steps 1-2 in Fig. 4)."""
-    boxes2d = jax.vmap(lambda b: box_ops.project_box3d_to_2d(
-        b, calib.tr, calib.p))(boxes3d)
-    tracks, pred2d = tracking.predict(state.tracks)
-    t2d, d2t, _ = association.associate(pred2d, tracks.active, boxes2d, valid,
-                                        params.iou_assoc, params.backend)
-    tracks = tracking.update(tracks, t2d, boxes2d, params.tracker)
-    tracks, d2t = tracking.spawn(tracks, boxes2d, valid, d2t)
-    tracks = tracking.set_box3d(tracks, d2t, boxes3d, valid)
-    # Refresh fleet-average size from the (trusted) anchor results.
-    n = jnp.maximum(jnp.sum(valid), 1)
-    mean_size = jnp.sum(jnp.where(valid[:, None], boxes3d[:, 3:6], 0.0),
-                        axis=0) / n
-    avg_size = jnp.where(jnp.sum(valid) > 0, mean_size, state.avg_size)
+    with jax.named_scope(STAGE_ASSOCIATE):
+        boxes2d = jax.vmap(lambda b: box_ops.project_box3d_to_2d(
+            b, calib.tr, calib.p))(boxes3d)
+        tracks, pred2d = tracking.predict(state.tracks)
+        t2d, d2t, _ = association.associate(pred2d, tracks.active, boxes2d,
+                                            valid, params.iou_assoc,
+                                            params.backend)
+        tracks = tracking.update(tracks, t2d, boxes2d, params.tracker)
+        tracks, d2t = tracking.spawn(tracks, boxes2d, valid, d2t)
+    with jax.named_scope(STAGE_BOXES):
+        tracks = tracking.set_box3d(tracks, d2t, boxes3d, valid)
+        # Refresh fleet-average size from the (trusted) anchor results.
+        n = jnp.maximum(jnp.sum(valid), 1)
+        mean_size = jnp.sum(jnp.where(valid[:, None], boxes3d[:, 3:6], 0.0),
+                            axis=0) / n
+        avg_size = jnp.where(jnp.sum(valid) > 0, mean_size, state.avg_size)
     out = FrameOutput(boxes3d=boxes3d, valid=valid, det_to_track=d2t,
                       track_boxes2d=pred2d)
     return MobyState(tracks=tracks, avg_size=avg_size, key=state.key), out
@@ -116,49 +131,55 @@ def transform_step(state: MobyState, points: jnp.ndarray,
     key, sub = jax.random.split(state.key)
 
     # --- tracking-based association (§3.2) --------------------------------
-    tracks, pred2d = tracking.predict(state.tracks)
-    if params.use_tba:
-        t2d, d2t, _ = association.associate(pred2d, tracks.active, det_boxes2d,
-                                            det_valid, params.iou_assoc,
-                                            params.backend)
-        tracks = tracking.update(tracks, t2d, det_boxes2d, params.tracker)
-        tracks, d2t = tracking.spawn(tracks, det_boxes2d, det_valid, d2t)
-    else:
-        # Ablation (Table 4, TRS-only): no association — every detection is
-        # treated as a new object.
-        d2t = jnp.full((d,), -1, jnp.int32)
+    with jax.named_scope(STAGE_ASSOCIATE):
+        tracks, pred2d = tracking.predict(state.tracks)
+        if params.use_tba:
+            t2d, d2t, _ = association.associate(
+                pred2d, tracks.active, det_boxes2d, det_valid,
+                params.iou_assoc, params.backend)
+            tracks = tracking.update(tracks, t2d, det_boxes2d,
+                                     params.tracker)
+            tracks, d2t = tracking.spawn(tracks, det_boxes2d, det_valid, d2t)
+        else:
+            # Ablation (Table 4, TRS-only): no association — every
+            # detection is treated as a new object.
+            d2t = jnp.full((d,), -1, jnp.int32)
 
     # --- point projection (§3.3) ------------------------------------------
     # Fused project + visibility + flat-index + label gather (ops backend).
-    labels = projection.project_and_label(points, label_img, calib,
-                                          params.backend)
-    clusters, cvalid, _ = projection.build_clusters(points, labels, d,
-                                                    params.pts_per_obj)
+    with jax.named_scope(STAGE_PROJECT):
+        labels = projection.project_and_label(points, label_img, calib,
+                                              params.backend)
+    with jax.named_scope(STAGE_CLUSTERS):
+        clusters, cvalid, _ = projection.build_clusters(points, labels, d,
+                                                        params.pts_per_obj)
 
     # --- point filtration (Algorithm 1) ------------------------------------
     # Associated objects carry a center prior from the previous 3D box.
-    t_idx0 = jnp.clip(d2t, 0, state.tracks.x.shape[0] - 1)
-    prior_ok = (d2t >= 0) & tracks.has_box3d[t_idx0]
-    prior_centers = tracks.box3d[t_idx0][:, :3]
-    keep = filtration.filter_clusters(clusters, cvalid, params.filtration,
-                                      prior_centers, prior_ok)
+    with jax.named_scope(STAGE_FILTRATION):
+        t_idx0 = jnp.clip(d2t, 0, state.tracks.x.shape[0] - 1)
+        prior_ok = (d2t >= 0) & tracks.has_box3d[t_idx0]
+        prior_centers = tracks.box3d[t_idx0][:, :3]
+        keep = filtration.filter_clusters(clusters, cvalid, params.filtration,
+                                          prior_centers, prior_ok)
 
     # --- RANSAC surface fitting --------------------------------------------
-    fit = ransac.ransac_planes(sub, clusters, keep, params.ransac,
-                               backend=params.backend)
+    with jax.named_scope(STAGE_RANSAC):
+        fit = ransac.ransac_planes(sub, clusters, keep, params.ransac,
+                                   backend=params.backend)
 
-    # --- 3D box estimation (Eqs. 1-2, Fig. 10) ------------------------------
-    t_idx = jnp.clip(d2t, 0, state.tracks.x.shape[0] - 1)
-    associated = (d2t >= 0) & tracks.has_box3d[t_idx]
-    prev_boxes = tracks.box3d[t_idx]
-    boxes3d, ok = box_estimation.estimate_boxes(
-        clusters, fit.inliers, keep, fit.normal, fit.ok, associated,
-        prev_boxes, state.avg_size, params.boxest)
-    valid = ok & det_valid
-
-    # --- write back for the next frame --------------------------------------
-    if params.use_tba:
-        tracks = tracking.set_box3d(tracks, d2t, boxes3d, valid)
+    # --- 3D box estimation (Eqs. 1-2, Fig. 10), written back for the next
+    # frame ------------------------------------------------------------------
+    with jax.named_scope(STAGE_BOXES):
+        t_idx = jnp.clip(d2t, 0, state.tracks.x.shape[0] - 1)
+        associated = (d2t >= 0) & tracks.has_box3d[t_idx]
+        prev_boxes = tracks.box3d[t_idx]
+        boxes3d, ok = box_estimation.estimate_boxes(
+            clusters, fit.inliers, keep, fit.normal, fit.ok, associated,
+            prev_boxes, state.avg_size, params.boxest)
+        valid = ok & det_valid
+        if params.use_tba:
+            tracks = tracking.set_box3d(tracks, d2t, boxes3d, valid)
 
     out = FrameOutput(boxes3d=boxes3d, valid=valid, det_to_track=d2t,
                       track_boxes2d=pred2d)

@@ -229,106 +229,147 @@ class FleetEngine:
     # ------------------------------------------------------------------
     def run(self, n_frames: int) -> RunReport:
         """Orchestrated serving: one device dispatch + one stats fetch per
-        frame for all S streams; byte-accurate shared-uplink/cloud timing."""
-        stack = self._stacked(n_frames)
+        frame for all S streams; byte-accurate shared-uplink/cloud timing.
+
+        Observed, each round is a ``fleet/round`` span whose phases are
+        its children (``parent`` = the round's index): ``fleet/inputs``
+        (the host->device puts, with their ``bytes`` and ``puts``),
+        ``fleet/telemetry``, ``fleet/dispatch``, ``fleet/fetch`` and
+        ``fleet/contention`` (uplink, cloud pool and latency bookkeeping,
+        with the round's ``senders``); ``fleet/prologue`` and
+        ``fleet/epilogue`` hold the run's set-up and report."""
         s_n = self.n_streams
         obs = obs_lib.make_observer(
             self.obs_config, n_streams=s_n, devices=self.stream_devices,
             policy=self.sparams.policy if self.use_fos else "",
             detector=self.detector, frame_dt=self.frame_dt,
             n_shards=self.n_shards)
-        want_audit = obs is not None and obs.cfg.want_audit
-        self.batcher.sink = obs
-        state = self._init_state()
-        edge_inf = self._edge_infer()   # (S,), frame-invariant
-        walls = np.zeros(s_n)
-        inflight_at = np.full(s_n, np.inf)
-        self.uplink.reset()
-        self.batcher.reset()
-        out = np.zeros((s_n, n_frames, step_lib.COL_ONBOARD + 1), np.float32)
+        with obs.measured_span("fleet/prologue") if obs is not None \
+                else _NULL_CTX:
+            stack = self._stacked(n_frames)
+            want_audit = obs is not None and obs.cfg.want_audit
+            if obs is not None:
+                # Bytes of one round's puts (the same every round).
+                in_bytes = sum(a[:, 0].nbytes for a in stack)
+            self.batcher.sink = obs
+            state = self._init_state()
+            edge_inf = self._edge_infer()   # (S,), frame-invariant
+            walls = np.zeros(s_n)
+            inflight_at = np.full(s_n, np.inf)
+            self.uplink.reset()
+            self.batcher.reset()
+            out = np.zeros((s_n, n_frames, step_lib.COL_ONBOARD + 1),
+                           np.float32)
 
         for t in range(n_frames):
-            inp = self._frame_inputs(stack, t)
-            arrived = walls >= inflight_at
-            if self.use_fos:
-                state = self._observe_telemetry(state, obs)
-            if want_audit:
-                # The only obs-added fetch: the state-resident policy
-                # inputs at decision time (one small (2, S) array).
-                pre_tel = np.asarray(
-                    scheduler.decision_telemetry(state.sched))
-            with obs.measured_span("fleet/dispatch", jit_fn=self._step,
-                                   frame=t) if obs is not None \
-                    else _NULL_CTX:
-                state, packed = self._step(
-                    state, inp, self._put(arrived, P("streams")),
-                    jnp.int32(t))
-            with obs.measured_span("fleet/fetch", frame=t) \
+            with obs.measured_span("fleet/round", frame=t) \
                     if obs is not None else _NULL_CTX:
-                pk = np.asarray(packed)        # the one fetch per frame
-            is_anchor = pk[:, step_lib.COL_IS_ANCHOR] > 0.5
-            send_test = pk[:, step_lib.COL_SEND_TEST] > 0.5
-            inflight_at[arrived] = np.inf
-
-            # Fleet-level contention: this round's uploads share the cell
-            # uplink; its cloud requests are served as one batch.
-            cloud_anchor = is_anchor & (self.mode != "moby_onboard")
-            senders = cloud_anchor | send_test
-            n_up = int(senders.sum())
-            roundtrip = np.zeros(s_n)
-            if n_up:
-                up = self.uplink.transfer_time(PC_BYTES, n_sharers=n_up)
-                down = self.uplink.transfer_time(RESULT_BYTES,
-                                                 n_sharers=n_up)
-                idxs = np.flatnonzero(senders)
-                done = self.batcher.submit_batch(
-                    [self.uplink.t + up] * n_up)
-                for j, s in enumerate(idxs):
-                    roundtrip[s] = (done[j] - self.uplink.t) + down
-                if obs is not None:
-                    bd = self.uplink.transfer_breakdown(
-                        PC_BYTES, up, n_sharers=n_up)
-                    obs.record_uplink("up", self.uplink.t, up, n_up,
-                                      PC_BYTES, bd["eff_mbps"])
-                    bdd = self.uplink.transfer_breakdown(
-                        RESULT_BYTES, down, n_sharers=n_up)
-                    for d in sorted(set(done)):
-                        obs.record_uplink("down", d, down,
-                                          done.count(d), RESULT_BYTES,
-                                          bdd["eff_mbps"])
-
-            lat = np.zeros(s_n)
-            onb = np.zeros(s_n)
-            for s in range(s_n):
-                if is_anchor[s]:
-                    lat[s] = edge_inf[s] \
-                        if self.mode == "moby_onboard" else roundtrip[s]
-                else:
-                    n_assoc = int(pk[s, step_lib.COL_N_ASSOC])
-                    n_new = max(int(pk[s, step_lib.COL_N_VALID]) - n_assoc, 0)
-                    onb[s] = onboard_transform_time(
-                        self.comps[s], n_assoc, n_new, self.use_tba,
-                        self._charge_fos)
-                    lat[s] = onb[s]
-                if send_test[s]:
-                    inflight_at[s] = walls[s] + roundtrip[s]
-
-            if want_audit:
-                kinds = np.where(is_anchor, "anchor",
-                                 np.where(send_test, "test", "transform"))
-                obs.audit_frame(t, kinds, pre_tel[0], pre_tel[1])
-            out[:, t, :step_lib.N_COLS] = pk
-            out[:, t, step_lib.COL_LATENCY] = lat
-            out[:, t, step_lib.COL_ONBOARD] = onb
-            walls += np.where(is_anchor, np.maximum(self.frame_dt, lat),
-                              self.frame_dt)
-            self.uplink.advance(self.frame_dt)
-        report = report_from_packed(out, devices=self.stream_devices)
-        report.frame_dt = self.frame_dt
-        if obs is not None:
-            self.batcher.sink = None
-            obs.finalize(report, busy_s_g=self.batcher.busy_s_g)
+                with obs.measured_span(
+                        "fleet/inputs", parent=t, frame=t, bytes=in_bytes,
+                        puts=len(stack)) if obs is not None else _NULL_CTX:
+                    inp = self._frame_inputs(stack, t)
+                arrived = walls >= inflight_at
+                pre_tel = None
+                with obs.measured_span("fleet/telemetry", parent=t,
+                                       frame=t) if obs is not None \
+                        else _NULL_CTX:
+                    if self.use_fos:
+                        state = self._observe_telemetry(state, obs)
+                    if want_audit:
+                        # The only obs-added fetch: the state-resident
+                        # policy inputs at decision time (one small (2, S)
+                        # array).
+                        pre_tel = np.asarray(
+                            scheduler.decision_telemetry(state.sched))
+                with obs.measured_span("fleet/dispatch", jit_fn=self._step,
+                                       parent=t, frame=t) \
+                        if obs is not None else _NULL_CTX:
+                    state, packed = self._step(
+                        state, inp, self._put(arrived, P("streams")),
+                        jnp.int32(t))
+                with obs.measured_span("fleet/fetch", parent=t, frame=t) \
+                        if obs is not None else _NULL_CTX:
+                    pk = np.asarray(packed)        # the one fetch per frame
+                with obs.measured_span("fleet/contention", parent=t,
+                                       frame=t) if obs is not None \
+                        else _NULL_CTX as phase:
+                    n_up = self._contend(t, pk, arrived, edge_inf, walls,
+                                         inflight_at, out, obs, pre_tel)
+                    if obs is not None:
+                        phase["senders"] = n_up
+        with obs.measured_span("fleet/epilogue") if obs is not None \
+                else _NULL_CTX:
+            report = report_from_packed(out, devices=self.stream_devices)
+            report.frame_dt = self.frame_dt
+            if obs is not None:
+                self.batcher.sink = None
+                obs.finalize(report, busy_s_g=self.batcher.busy_s_g)
         return report
+
+    def _contend(self, t: int, pk: np.ndarray, arrived: np.ndarray,
+                 edge_inf: np.ndarray, walls: np.ndarray,
+                 inflight_at: np.ndarray, out: np.ndarray,
+                 obs: Optional[obs_lib.Observer], pre_tel) -> int:
+        """Round ``t``'s fleet-level contention from its fetched stats
+        ``pk``: this round's uploads share the cell uplink and its cloud
+        requests are served as one batch. Writes the round's latencies
+        into ``out`` and advances ``walls``, ``inflight_at`` and the
+        uplink clock in place; returns the round's sender count.
+        ``pre_tel``: the audit's decision-time telemetry, or None."""
+        s_n = self.n_streams
+        is_anchor = pk[:, step_lib.COL_IS_ANCHOR] > 0.5
+        send_test = pk[:, step_lib.COL_SEND_TEST] > 0.5
+        inflight_at[arrived] = np.inf
+
+        cloud_anchor = is_anchor & (self.mode != "moby_onboard")
+        senders = cloud_anchor | send_test
+        n_up = int(senders.sum())
+        roundtrip = np.zeros(s_n)
+        if n_up:
+            up = self.uplink.transfer_time(PC_BYTES, n_sharers=n_up)
+            down = self.uplink.transfer_time(RESULT_BYTES, n_sharers=n_up)
+            idxs = np.flatnonzero(senders)
+            done = self.batcher.submit_batch([self.uplink.t + up] * n_up)
+            for j, s in enumerate(idxs):
+                roundtrip[s] = (done[j] - self.uplink.t) + down
+            if obs is not None:
+                bd = self.uplink.transfer_breakdown(
+                    PC_BYTES, up, n_sharers=n_up)
+                obs.record_uplink("up", self.uplink.t, up, n_up,
+                                  PC_BYTES, bd["eff_mbps"])
+                bdd = self.uplink.transfer_breakdown(
+                    RESULT_BYTES, down, n_sharers=n_up)
+                for d in sorted(set(done)):
+                    obs.record_uplink("down", d, down, done.count(d),
+                                      RESULT_BYTES, bdd["eff_mbps"])
+
+        lat = np.zeros(s_n)
+        onb = np.zeros(s_n)
+        for s in range(s_n):
+            if is_anchor[s]:
+                lat[s] = edge_inf[s] \
+                    if self.mode == "moby_onboard" else roundtrip[s]
+            else:
+                n_assoc = int(pk[s, step_lib.COL_N_ASSOC])
+                n_new = max(int(pk[s, step_lib.COL_N_VALID]) - n_assoc, 0)
+                onb[s] = onboard_transform_time(
+                    self.comps[s], n_assoc, n_new, self.use_tba,
+                    self._charge_fos)
+                lat[s] = onb[s]
+            if send_test[s]:
+                inflight_at[s] = walls[s] + roundtrip[s]
+
+        if pre_tel is not None:
+            kinds = np.where(is_anchor, "anchor",
+                             np.where(send_test, "test", "transform"))
+            obs.audit_frame(t, kinds, pre_tel[0], pre_tel[1])
+        out[:, t, :step_lib.N_COLS] = pk
+        out[:, t, step_lib.COL_LATENCY] = lat
+        out[:, t, step_lib.COL_ONBOARD] = onb
+        walls += np.where(is_anchor, np.maximum(self.frame_dt, lat),
+                          self.frame_dt)
+        self.uplink.advance(self.frame_dt)
+        return n_up
 
     # ------------------------------------------------------------------
     def _init_state(self) -> step_lib.FleetState:

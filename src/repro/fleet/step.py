@@ -44,6 +44,12 @@ from jax.sharding import PartitionSpec as P
 from repro.core import metrics, scheduler, transform
 from repro.serving.common import ComponentTimes, nominal_transform_time
 
+# Stage names (``jax.named_scope``) of the step's work around the transform,
+# beside ``transform.STAGE_*``: the scheduler's pre and post, and the F1
+# score with the packing of the stats row.
+STAGE_SCHEDULER = "scheduler"
+STAGE_SCORE = "score"
+
 # Columns of the packed per-stream stats row (the one host fetch per frame).
 COL_IS_ANCHOR = 0
 COL_SEND_TEST = 1
@@ -106,11 +112,12 @@ def _stream_step(state: FleetState, inp: FrameInputs,
                  test_arrived: jnp.ndarray, t: jnp.ndarray,
                  calib, params, sparams, use_fos: bool):
     """One stream, one frame — fully traceable (no host branching)."""
-    if use_fos:
-        actions = scheduler.scheduler_pre(state.sched, sparams)
-    else:
-        actions = scheduler.SchedulerActions(send_test=jnp.bool_(False),
-                                             run_as_anchor=t == 0)
+    with jax.named_scope(STAGE_SCHEDULER):
+        if use_fos:
+            actions = scheduler.scheduler_pre(state.sched, sparams)
+        else:
+            actions = scheduler.SchedulerActions(send_test=jnp.bool_(False),
+                                                 run_as_anchor=t == 0)
     mstate, out = transform.fused_step(
         state.moby, inp.points, inp.det2d, inp.val2d, inp.label_img,
         inp.det3d, inp.val3d, actions.run_as_anchor, calib, params)
@@ -118,25 +125,29 @@ def _stream_step(state: FleetState, inp: FrameInputs,
     # The cloud's answer for an in-flight test frame is that frame's own 3D
     # detections, latched on-device at send time — the host only supplies
     # the *arrival timing* (it owns the network clock).
-    tb = jnp.where(test_arrived, state.inflight_boxes, state.sched.buf_boxes)
-    tv = jnp.where(test_arrived, state.inflight_valid, state.sched.buf_valid)
-    sched_state = state.sched
-    if use_fos:
-        sched_state = scheduler.scheduler_post(
-            sched_state, actions, out.boxes3d, out.valid, test_arrived,
-            tb, tv, sparams)
-    new_ib = jnp.where(actions.send_test, inp.det3d, state.inflight_boxes)
-    new_iv = jnp.where(actions.send_test, inp.val3d, state.inflight_valid)
+    with jax.named_scope(STAGE_SCHEDULER):
+        tb = jnp.where(test_arrived, state.inflight_boxes,
+                       state.sched.buf_boxes)
+        tv = jnp.where(test_arrived, state.inflight_valid,
+                       state.sched.buf_valid)
+        sched_state = state.sched
+        if use_fos:
+            sched_state = scheduler.scheduler_post(
+                sched_state, actions, out.boxes3d, out.valid, test_arrived,
+                tb, tv, sparams)
+        new_ib = jnp.where(actions.send_test, inp.det3d, state.inflight_boxes)
+        new_iv = jnp.where(actions.send_test, inp.val3d, state.inflight_valid)
 
-    f1, prec, rec = metrics.f1_score(out.boxes3d, out.valid,
-                                     inp.gt_boxes, inp.gt_visible)
-    n_assoc = jnp.sum((out.det_to_track >= 0) & out.valid)
-    n_valid = jnp.sum(out.valid)
-    packed = jnp.stack([
-        actions.run_as_anchor.astype(jnp.float32),
-        actions.send_test.astype(jnp.float32),
-        f1, prec, rec,
-        n_assoc.astype(jnp.float32), n_valid.astype(jnp.float32)])
+    with jax.named_scope(STAGE_SCORE):
+        f1, prec, rec = metrics.f1_score(out.boxes3d, out.valid,
+                                         inp.gt_boxes, inp.gt_visible)
+        n_assoc = jnp.sum((out.det_to_track >= 0) & out.valid)
+        n_valid = jnp.sum(out.valid)
+        packed = jnp.stack([
+            actions.run_as_anchor.astype(jnp.float32),
+            actions.send_test.astype(jnp.float32),
+            f1, prec, rec,
+            n_assoc.astype(jnp.float32), n_valid.astype(jnp.float32)])
     return FleetState(mstate, sched_state, new_ib, new_iv), packed
 
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import time
 from typing import Dict, List, Optional
 
@@ -62,6 +63,11 @@ class ObsConfig:
         return self.want_metrics or self.want_trace or self.want_audit
 
 
+# Per-process id of each Observer (one per engine run): the ``run`` field
+# of its measured spans.
+_RUN_IDS = itertools.count()
+
+
 def make_observer(cfg: Optional[ObsConfig], **run_info
                   ) -> Optional["Observer"]:
     """The engines' entry point: None (or an all-off config) -> None, so
@@ -93,10 +99,10 @@ class Observer:
         self.gpu_busy: List[Dict] = []
         # measured host spans (real wall clock, zeroed at first span)
         self.measured: List[Dict] = []
-        self._host_t0: Optional[float] = None
+        self.run_id = next(_RUN_IDS)
+        self._host_t0_ns: Optional[int] = None
         self.retraces: Dict[str, int] = {}
         self.compile_s: Dict[str, float] = {}
-        self._cache_sizes: Dict[int, int] = {}
         # audit
         self.audit = AuditLog()
         self._metrics_flushed = False
@@ -132,24 +138,34 @@ class Observer:
 
     # -- measured host spans ----------------------------------------------
     @contextlib.contextmanager
-    def measured_span(self, name: str, jit_fn=None, **args):
+    def measured_span(self, name: str, jit_fn=None, parent=None, **args):
         """Real wall-clock span around a host region (dispatch / fetch /
         compile). ``jit_fn``: the jitted callable running inside — its
         compilation-cache growth marks the span as a retrace/compile and
-        feeds the per-step retrace counters."""
+        feeds the per-step retrace counters. ``parent``: the index of the
+        enclosing span (a fleet round's, for its phases).
+
+        The record holds ``t0`` (seconds since this run's first span),
+        ``dur``, ``t_ns`` (the absolute ``perf_counter_ns`` at the start,
+        one clock for the spans of every run in the process), ``parent``
+        and ``run`` (this run's id) beside ``args``. It is yielded, so
+        the region can add args it computes (a count known only inside)."""
         try:
             import jax
             ann = jax.profiler.TraceAnnotation(f"moby/{name}")
         except Exception:                      # pragma: no cover
             ann = contextlib.nullcontext()
         before = self._jit_cache_size(jit_fn)
-        t0 = time.perf_counter()
-        if self._host_t0 is None:
-            self._host_t0 = t0
+        extra: Dict = {}
+        t_ns = time.perf_counter_ns()
+        if self._host_t0_ns is None:
+            self._host_t0_ns = t_ns
         with ann:
-            yield
-        dur = time.perf_counter() - t0
-        rec = {"name": name, "t0": t0 - self._host_t0, "dur": dur, **args}
+            yield extra
+        dur = (time.perf_counter_ns() - t_ns) * 1e-9
+        rec = {"name": name, "t0": (t_ns - self._host_t0_ns) * 1e-9,
+               "dur": dur, "t_ns": t_ns, "parent": parent,
+               "run": self.run_id, **args, **extra}
         after = self._jit_cache_size(jit_fn)
         if after is not None and before is not None and after > before:
             rec["compiled"] = True
@@ -158,16 +174,14 @@ class Observer:
             self.compile_s[name] = self.compile_s.get(name, 0.0) + dur
         self.measured.append(rec)
 
-    def _jit_cache_size(self, jit_fn) -> Optional[int]:
+    @staticmethod
+    def _jit_cache_size(jit_fn) -> Optional[int]:
         if jit_fn is None:
             return None
-        key = id(jit_fn)
         try:
-            size = int(jit_fn._cache_size())
+            return int(jit_fn._cache_size())
         except Exception:                      # pragma: no cover
             return None
-        self._cache_sizes[key] = size
-        return size
 
     # -- scheduler audit --------------------------------------------------
     def note_telemetry(self, bw_mbps, edge_cost_s, offload_cost_s) -> None:

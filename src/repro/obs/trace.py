@@ -17,9 +17,10 @@ Lanes (``pid`` = track, ``tid`` = lane within it):
 * ``cloud``   — one lane per pool GPU: batch busy intervals (args carry
   batch size and queue wait). Busy spans never overlap within a lane and
   their durations sum to the pool's ``busy_s_g`` accounting;
-* ``host``    — *measured* wall-clock spans around dispatch/fetch
-  (``Observer.measured_span``), its own clock starting at 0, so modeled
-  vs. real time can be compared side by side.
+* ``host``    — *measured* wall-clock spans (``Observer.measured_span``:
+  each phase of a fleet round, or a single-stream engine's steps and
+  fetch), its own clock starting at 0, so modeled vs. real time can be
+  compared side by side.
 
 :func:`trace_from_report` also works without an attached observer: the
 per-stream lanes are reconstructed exactly from the packed (S, F) arrays

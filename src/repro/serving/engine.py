@@ -244,7 +244,7 @@ class MobyEngine:
                 else:
                     latency = self._cloud_roundtrip(obs, t0=wall,
                                                     record_gpu=True)
-                with obs.measured_span("moby/anchor_step",
+                with obs.measured_span("anchor_step",
                                        jit_fn=self._anchor_step,
                                        frame=t) if obs is not None \
                         else _NULL_CTX:
@@ -267,7 +267,7 @@ class MobyEngine:
                     boxes2d, val2d, label_img = scenes.oracle_detect_2d(
                         frame, self.rng)
                     points = frame.points
-                with obs.measured_span("moby/transform_step",
+                with obs.measured_span("transform_step",
                                        jit_fn=self._transform_step,
                                        frame=t) if obs is not None \
                         else _NULL_CTX:
@@ -303,7 +303,7 @@ class MobyEngine:
             # detection counts driving the on-board time model.
             gt_boxes = tf.gt_boxes if tf is not None else frame.gt_boxes
             gt_vis = tf.gt_visible if tf is not None else frame.visible_gt()
-            with obs.measured_span("moby/frame_stats_fetch",
+            with obs.measured_span("frame_stats_fetch",
                                    jit_fn=_frame_stats,
                                    frame=t) if obs is not None \
                     else _NULL_CTX:

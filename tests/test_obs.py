@@ -211,8 +211,8 @@ class TestTrace:
         host = [e for e in evs if e["ph"] == "X"
                 and e["pid"] == trace_lib.PID_HOST]
         names = {e["name"] for e in host}
-        assert "moby/frame_stats_fetch" in names
-        assert "moby/transform_step" in names
+        assert "frame_stats_fetch" in names
+        assert "transform_step" in names
 
 
 # ---------------------------------------------------------------------------
