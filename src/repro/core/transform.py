@@ -88,19 +88,31 @@ class FrameOutput(NamedTuple):
     track_boxes2d: jnp.ndarray   # (T, 4) predicted boxes (diagnostics)
 
 
-def anchor_step(state: MobyState, boxes3d: jnp.ndarray, valid: jnp.ndarray,
-                calib: projection.Calibration,
-                params: TransformParams = TransformParams()) -> tuple[MobyState, FrameOutput]:
-    """Ingest cloud 3D detections at an anchor frame (steps 1-2 in Fig. 4)."""
-    with jax.named_scope(STAGE_ASSOCIATE):
-        boxes2d = jax.vmap(lambda b: box_ops.project_box3d_to_2d(
-            b, calib.tr, calib.p))(boxes3d)
-        tracks, pred2d = tracking.predict(state.tracks)
-        t2d, d2t, _ = association.associate(pred2d, tracks.active, boxes2d,
-                                            valid, params.iou_assoc,
-                                            params.backend)
-        tracks = tracking.update(tracks, t2d, boxes2d, params.tracker)
-        tracks, d2t = tracking.spawn(tracks, boxes2d, valid, d2t)
+def _associate(tracks: tracking.TrackState, boxes2d: jnp.ndarray,
+               valid: jnp.ndarray, params: TransformParams):
+    """Kalman predict, match the predicted boxes to ``boxes2d`` (IoU +
+    auction) and update/spawn tracks. Returns (tracks, det_to_track,
+    predicted 2D boxes)."""
+    tracks, pred2d = tracking.predict(tracks)
+    t2d, d2t, _ = association.associate(pred2d, tracks.active, boxes2d,
+                                        valid, params.iou_assoc,
+                                        params.backend)
+    tracks = tracking.update(tracks, t2d, boxes2d, params.tracker)
+    tracks, d2t = tracking.spawn(tracks, boxes2d, valid, d2t)
+    return tracks, d2t, pred2d
+
+
+def _cloud_boxes2d(boxes3d: jnp.ndarray,
+                   calib: projection.Calibration) -> jnp.ndarray:
+    return jax.vmap(lambda b: box_ops.project_box3d_to_2d(
+        b, calib.tr, calib.p))(boxes3d)
+
+
+def _anchor_tail(state: MobyState, tracks: tracking.TrackState,
+                 d2t: jnp.ndarray, pred2d: jnp.ndarray, boxes3d: jnp.ndarray,
+                 valid: jnp.ndarray) -> tuple[MobyState, FrameOutput]:
+    """An associated anchor frame: write the cloud boxes onto the tracks
+    and refresh the fleet-average object size."""
     with jax.named_scope(STAGE_BOXES):
         tracks = tracking.set_box3d(tracks, d2t, boxes3d, valid)
         # Refresh fleet-average size from the (trusted) anchor results.
@@ -113,37 +125,15 @@ def anchor_step(state: MobyState, boxes3d: jnp.ndarray, valid: jnp.ndarray,
     return MobyState(tracks=tracks, avg_size=avg_size, key=state.key), out
 
 
-def transform_step(state: MobyState, points: jnp.ndarray,
-                   det_boxes2d: jnp.ndarray, det_valid: jnp.ndarray,
-                   label_img: jnp.ndarray, calib: projection.Calibration,
-                   params: TransformParams = TransformParams()) -> tuple[MobyState, FrameOutput]:
-    """Transform one non-anchor frame (steps 3-4 in Fig. 4).
-
-    Args:
-      state: Moby per-stream state.
-      points: (N, 3) LiDAR points.
-      det_boxes2d: (D, 4) 2D detections [x1,y1,x2,y2].
-      det_valid: (D,) mask.
-      label_img: (H, W) int32 instance-id image; id i+1 = detection slot i.
-      calib: sensor calibration.
-    """
-    d = det_boxes2d.shape[0]
+def _transform_tail(state: MobyState, tracks: tracking.TrackState,
+                    d2t: jnp.ndarray, pred2d: jnp.ndarray,
+                    points: jnp.ndarray, det_valid: jnp.ndarray,
+                    label_img: jnp.ndarray, calib: projection.Calibration,
+                    params: TransformParams) -> tuple[MobyState, FrameOutput]:
+    """An associated non-anchor frame: projection, clusters, filtration,
+    RANSAC and 3D boxes, written back onto the tracks."""
+    d = det_valid.shape[0]
     key, sub = jax.random.split(state.key)
-
-    # --- tracking-based association (§3.2) --------------------------------
-    with jax.named_scope(STAGE_ASSOCIATE):
-        tracks, pred2d = tracking.predict(state.tracks)
-        if params.use_tba:
-            t2d, d2t, _ = association.associate(
-                pred2d, tracks.active, det_boxes2d, det_valid,
-                params.iou_assoc, params.backend)
-            tracks = tracking.update(tracks, t2d, det_boxes2d,
-                                     params.tracker)
-            tracks, d2t = tracking.spawn(tracks, det_boxes2d, det_valid, d2t)
-        else:
-            # Ablation (Table 4, TRS-only): no association — every
-            # detection is treated as a new object.
-            d2t = jnp.full((d,), -1, jnp.int32)
 
     # --- point projection (§3.3) ------------------------------------------
     # Fused project + visibility + flat-index + label gather (ops backend).
@@ -186,6 +176,44 @@ def transform_step(state: MobyState, points: jnp.ndarray,
     return MobyState(tracks=tracks, avg_size=state.avg_size, key=key), out
 
 
+def anchor_step(state: MobyState, boxes3d: jnp.ndarray, valid: jnp.ndarray,
+                calib: projection.Calibration,
+                params: TransformParams = TransformParams()) -> tuple[MobyState, FrameOutput]:
+    """Ingest cloud 3D detections at an anchor frame (steps 1-2 in Fig. 4)."""
+    with jax.named_scope(STAGE_ASSOCIATE):
+        tracks, d2t, pred2d = _associate(
+            state.tracks, _cloud_boxes2d(boxes3d, calib), valid, params)
+    return _anchor_tail(state, tracks, d2t, pred2d, boxes3d, valid)
+
+
+def transform_step(state: MobyState, points: jnp.ndarray,
+                   det_boxes2d: jnp.ndarray, det_valid: jnp.ndarray,
+                   label_img: jnp.ndarray, calib: projection.Calibration,
+                   params: TransformParams = TransformParams()) -> tuple[MobyState, FrameOutput]:
+    """Transform one non-anchor frame (steps 3-4 in Fig. 4).
+
+    Args:
+      state: Moby per-stream state.
+      points: (N, 3) LiDAR points.
+      det_boxes2d: (D, 4) 2D detections [x1,y1,x2,y2].
+      det_valid: (D,) mask.
+      label_img: (H, W) int32 instance-id image; id i+1 = detection slot i.
+      calib: sensor calibration.
+    """
+    # --- tracking-based association (§3.2) --------------------------------
+    with jax.named_scope(STAGE_ASSOCIATE):
+        if params.use_tba:
+            tracks, d2t, pred2d = _associate(state.tracks, det_boxes2d,
+                                             det_valid, params)
+        else:
+            # Ablation (Table 4, TRS-only): no association — every
+            # detection is treated as a new object.
+            tracks, pred2d = tracking.predict(state.tracks)
+            d2t = jnp.full((det_boxes2d.shape[0],), -1, jnp.int32)
+    return _transform_tail(state, tracks, d2t, pred2d, points, det_valid,
+                           label_img, calib, params)
+
+
 def fused_step(state: MobyState, points: jnp.ndarray,
                det_boxes2d: jnp.ndarray, det_valid: jnp.ndarray,
                label_img: jnp.ndarray, cloud_boxes3d: jnp.ndarray,
@@ -195,21 +223,48 @@ def fused_step(state: MobyState, points: jnp.ndarray,
                ) -> tuple[MobyState, FrameOutput]:
     """One frame with its treatment resolved **on device**.
 
-    ``lax.cond`` selects between :func:`anchor_step` (ingest the cloud 3D
-    result) and :func:`transform_step` (2D->3D transformation) from a
-    traced ``is_anchor`` flag, so no host-side ``bool()`` sync is needed to
-    branch. Batched engines ``vmap`` this over streams — each stream takes
-    its own branch — and ``lax.scan`` can wrap it for device-resident
-    multi-frame runs (repro.fleet).
+    A traced ``is_anchor`` flag selects between :func:`anchor_step` (ingest
+    the cloud 3D result) and :func:`transform_step` (2D->3D
+    transformation), so no host-side ``bool()`` sync is needed to branch.
+    Batched engines ``vmap`` this over streams — each stream takes its own
+    branch — and ``lax.scan`` can wrap it for device-resident multi-frame
+    runs (repro.fleet).
+
+    Under ``vmap`` the batched ``lax.cond`` lowers to a select that runs
+    both branches for every stream, so the association — both branches
+    run one, on different boxes — is taken out of it: each stream's own
+    boxes (the projected cloud boxes or its 2D detections) are selected
+    first and associated once, and the cond covers only the tails. Without
+    tracking-based association the transform branch associates nothing,
+    and the whole steps stay inside the cond.
     """
+    if not params.use_tba:
+        def _anchor(op):
+            st, _pts, _b2, _v2, _li, b3, v3 = op
+            return anchor_step(st, b3, v3, calib, params)
+
+        def _transform(op):
+            st, pts, b2, v2, li, _b3, _v3 = op
+            return transform_step(st, pts, b2, v2, li, calib, params)
+
+        return jax.lax.cond(is_anchor, _anchor, _transform,
+                            (state, points, det_boxes2d, det_valid,
+                             label_img, cloud_boxes3d, cloud_valid))
+
+    with jax.named_scope(STAGE_ASSOCIATE):
+        boxes2d = jnp.where(is_anchor, _cloud_boxes2d(cloud_boxes3d, calib),
+                            det_boxes2d)
+        valid = jnp.where(is_anchor, cloud_valid, det_valid)
+        tracks, d2t, pred2d = _associate(state.tracks, boxes2d, valid, params)
+
     def _anchor(op):
-        st, _pts, _b2, _v2, _li, b3, v3 = op
-        return anchor_step(st, b3, v3, calib, params)
+        st, tr, d2, p2, _pts, _v2, _li, b3, v3 = op
+        return _anchor_tail(st, tr, d2, p2, b3, v3)
 
     def _transform(op):
-        st, pts, b2, v2, li, _b3, _v3 = op
-        return transform_step(st, pts, b2, v2, li, calib, params)
+        st, tr, d2, p2, pts, v2, li, _b3, _v3 = op
+        return _transform_tail(st, tr, d2, p2, pts, v2, li, calib, params)
 
     return jax.lax.cond(is_anchor, _anchor, _transform,
-                        (state, points, det_boxes2d, det_valid, label_img,
-                         cloud_boxes3d, cloud_valid))
+                        (state, tracks, d2t, pred2d, points, det_valid,
+                         label_img, cloud_boxes3d, cloud_valid))

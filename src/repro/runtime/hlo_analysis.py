@@ -205,3 +205,19 @@ def compiled_hlo_text(fn, mesh, in_specs, out_spec, avals) -> str:
         in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
         out_shardings=NamedSharding(mesh, out_spec))
     return jitted.lower(*avals).compile().as_text()
+
+
+_WHILE_RE = re.compile(r"=\s.*?\swhile\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def while_loops(hlo_text: str) -> list:
+    """The ``op_name`` metadata of every ``while`` instruction in compiled
+    HLO text ("" where it has none), one entry per loop the executable
+    holds, so a caller can count the loops of a named scope."""
+    out = []
+    for line in hlo_text.splitlines():
+        if _WHILE_RE.search(line):
+            m = _OP_NAME_RE.search(line)
+            out.append(m.group(1) if m else "")
+    return out

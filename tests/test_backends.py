@@ -6,15 +6,20 @@ entire transformation path (transform_step / fused_step / a multi-stream
 FleetEngine slice) must produce identical frame treatments, boxes, and F1
 under ``backend="pallas"`` (interpret off-TPU) and ``backend="ref"``.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import ops
-from repro.core import metrics, projection, transform
+from repro.core import metrics, projection, scheduler, transform
 from repro.data import scenes
 from repro.fleet import FleetEngine
+from repro.fleet import step as step_lib
+from repro.runtime import hlo_analysis
 from repro.serving import tape as tape_lib
 from repro.serving import twotier
 
@@ -131,34 +136,113 @@ class TestTransformParity:
             np.testing.assert_allclose(fr, fp, atol=1e-5,
                                        err_msg=f"frame {t} f1")
 
-    def test_fused_step_both_branches(self, shared_tape):
-        """vmapped fused_step: one stream takes the anchor branch, one the
-        transform branch — under both backends, jitted."""
-        cfg = _cfg()
-        tr, p = scenes.make_calibration(cfg)
-        calib = projection.Calibration(tr=jnp.asarray(tr), p=jnp.asarray(p),
-                                       height=cfg.img_h, width=cfg.img_w)
+    @pytest.mark.parametrize("use_tba", [True, False])
+    @pytest.mark.parametrize("anchors", [(True, False), (False, True),
+                                         (True, True), (False, False)])
+    def test_fused_step_both_branches(self, shared_tape, anchors, use_tba):
+        """vmapped fused_step, each stream taking its own branch, under both
+        backends, jitted: the same frame outputs and tracker state as each
+        stream's own anchor_step / transform_step."""
         f = shared_tape.frame(1)
+        mask = jnp.array(anchors)
+        results = {}
+        for backend in ("ref", "pallas"):
+            params = transform.TransformParams(backend=backend,
+                                               use_tba=use_tba)
+            state = _seeded_pair(shared_tape, params)
+            steps = _jitted_steps(params)
+            fused = steps["fused"](state, jnp.asarray(f.points),
+                                   jnp.asarray(f.det2d), jnp.asarray(f.val2d),
+                                   jnp.asarray(f.label_img),
+                                   jnp.asarray(f.det3d), jnp.asarray(f.val3d),
+                                   mask)
+            own = [steps["anchor" if a else "transform"](
+                jax.tree.map(lambda x, s=s: x[s], state), f)
+                for s, a in enumerate(anchors)]
+            for s, (st, out) in enumerate(own):
+                got = jax.tree.map(lambda x, s=s: x[s], fused)
+                _assert_same_step(got, (st, out), f"{backend} stream {s}")
+            results[backend] = tuple(np.asarray(x) for x in
+                                     (fused[1].boxes3d, fused[1].valid))
 
-        def run(backend):
-            params = transform.TransformParams(backend=backend)
-            keys = jax.vmap(jax.random.key)(jnp.arange(2))
-            state = jax.vmap(
-                lambda k: transform.init_state(2 * cfg.max_obj, k))(keys)
-            step = jax.jit(jax.vmap(
-                lambda st, anchor: transform.fused_step(
-                    st, jnp.asarray(f.points), jnp.asarray(f.det2d),
-                    jnp.asarray(f.val2d), jnp.asarray(f.label_img),
-                    jnp.asarray(f.det3d), jnp.asarray(f.val3d), anchor,
-                    calib, params)))
-            _, out = step(state, jnp.array([True, False]))
-            return np.asarray(out.boxes3d), np.asarray(out.valid)
-
-        b_ref, v_ref = run("ref")
-        b_pal, v_pal = run("pallas")
+        (b_ref, v_ref), (b_pal, v_pal) = results["ref"], results["pallas"]
         np.testing.assert_array_equal(v_ref, v_pal)
         np.testing.assert_allclose(b_ref[v_ref], b_pal[v_pal],
                                    rtol=1e-4, atol=1e-4)
+
+    def test_fleet_step_runs_one_association(self, shared_tape):
+        """The compiled fleet step holds the auction's while loops of one
+        association: one per epsilon phase (0.1, 0.01, 1e-3, 1e-4), not
+        one set for each branch of the anchor/transform cond."""
+        n = 2
+        step = step_lib.make_fleet_step(
+            _calib(), transform.TransformParams(backend="ref"),
+            scheduler.SchedulerParams())
+        f = shared_tape.frame(0)
+        inputs = step_lib.FrameInputs(*(
+            jnp.stack([jnp.asarray(getattr(f, k))] * n)
+            for k in ("points", "det2d", "val2d", "label_img", "det3d",
+                      "val3d", "gt_boxes", "gt_visible")))
+        state = step_lib.init_fleet_state(n, _cfg().max_obj)
+        text = step.lower(state, inputs, jnp.zeros((n,), bool),
+                          jnp.int32(0)).compile().as_text()
+        auction = re.compile(r"(^|/)(vmap\()*%s\)*/while$"
+                             % transform.STAGE_ASSOCIATE)
+        loops = [name for name in hlo_analysis.while_loops(text)
+                 if auction.search(name)]
+        assert len(loops) == 4, loops
+
+
+def _calib():
+    cfg = _cfg()
+    tr, p = scenes.make_calibration(cfg)
+    return projection.Calibration(tr=jnp.asarray(tr), p=jnp.asarray(p),
+                                  height=cfg.img_h, width=cfg.img_w)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_steps(params):
+    """Jitted anchor/transform steps on a FrameTape and the vmapped fused
+    step, compiled once per params across the parametrised cases."""
+    calib = _calib()
+    anchor = jax.jit(lambda st, f: transform.anchor_step(
+        st, jnp.asarray(f.det3d), jnp.asarray(f.val3d), calib, params))
+    trans = jax.jit(lambda st, f: transform.transform_step(
+        st, jnp.asarray(f.points), jnp.asarray(f.det2d),
+        jnp.asarray(f.val2d), jnp.asarray(f.label_img), calib, params))
+    fused = jax.jit(jax.vmap(
+        lambda st, pts, b2, v2, li, b3, v3, anchor: transform.fused_step(
+            st, pts, b2, v2, li, b3, v3, anchor, calib, params),
+        in_axes=(0, None, None, None, None, None, None, 0)))
+    return {"anchor": anchor, "transform": trans, "fused": fused}
+
+
+def _seeded_pair(tape, params):
+    """Two streams of different keys, each anchored on frame 0, so frame
+    1's association matches live tracks."""
+    cfg = _cfg()
+    steps = _jitted_steps(params)
+    states = []
+    for s in range(2):
+        st = transform.init_state(2 * cfg.max_obj, jax.random.key(s))
+        states.append(steps["anchor"](st, tape.frame(0))[0])
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+
+
+def _assert_same_step(got, want, msg):
+    """Frame outputs and the whole per-stream state, leaf by leaf: integer
+    and boolean leaves (matches, track slots, keys) exactly, float leaves
+    to a few ulp (a vmapped box fit can round its last bit otherwise)."""
+    def leaves(tree):
+        return [np.asarray(jax.random.key_data(x)
+                           if jnp.issubdtype(x.dtype, jax.dtypes.prng_key)
+                           else x) for x in jax.tree.leaves(tree)]
+    for i, (g, w) in enumerate(zip(leaves(got), leaves(want))):
+        if np.issubdtype(w.dtype, np.floating):
+            np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6,
+                                       err_msg=f"{msg} leaf {i}")
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=f"{msg} leaf {i}")
 
 
 class TestFleetParity:
