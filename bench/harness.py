@@ -4,8 +4,10 @@ A run serves one cell of ``BENCHMARK.json``: a fleet configuration
 (``bench/configs/<config>.json``) under a traffic mix
 (``bench/traffic/<traffic>.json``). It records the cell's tapes from the
 seed with the benchmark's own generator, builds one ``FleetEngine`` from
-an ``api.Scenario`` the way ``api.Session`` builds a fleet engine, hands
-it the tapes, warms it up with one drive, and then calls
+an ``api.Scenario`` the way ``api.Session`` builds a fleet engine, on the
+chip layout the configuration states (``"mesh": {"streams": M}``: the
+stream axis sharded over M chips; absent, one chip), hands it the tapes,
+warms it up with one drive, and then calls
 ``FleetEngine.run(W)`` ("a drive": W rounds, each one frame of every
 vehicle) back to back until the window's seconds have passed. The loop is
 closed: a round is due when the previous round's results are on the
@@ -85,6 +87,18 @@ class Cell:
             raise HarnessError(f"loop {self.traffic['loop']!r} is not run; "
                                f"the harness runs {LOOPS}")
         self.chips = int(entry["chips"])
+        mesh = self.config.get("mesh", {"streams": 1})
+        if not isinstance(mesh, dict) or set(mesh) != {"streams"}:
+            raise HarnessError(f"mesh {mesh!r} is not run; the harness "
+                               f"shards the stream axis alone")
+        self.mesh = int(mesh["streams"])
+        if self.mesh != self.chips:
+            raise HarnessError(f"the configuration lays the streams over "
+                               f"{self.mesh} chip(s), the cell asks for "
+                               f"{self.chips}")
+        if self.streams % self.mesh:
+            raise HarnessError(f"{self.streams} streams do not divide over "
+                               f"a mesh of {self.mesh} chips")
         self.end_to_end = [m for m in spec["end_to_end"]
                            if name in m.get("workloads", [name])]
         self.per_layer = [m for m in spec["per_layer"]
@@ -128,7 +142,8 @@ def record_tapes(cell: Cell, seed: int):
 
 def build_engine(cell: Cell, seed: int, tapes):
     """One FleetEngine with the arguments ``api.Session`` gives a fleet
-    scenario, plus the benchmark's tapes."""
+    scenario, plus the benchmark's tapes. A cell on M > 1 chips passes
+    ``mesh=M``: a 1-D ``streams`` mesh, each chip stepping S/M streams."""
     from repro import api
     from repro.fleet.cloud import CloudBatcherConfig
     from repro.fleet.engine import FleetEngine
@@ -142,6 +157,7 @@ def build_engine(cell: Cell, seed: int, tapes):
         cloud=CloudBatcherConfig(n_gpus=dep["cloud"]["n_gpus"],
                                  marginal=dep["cloud"]["marginal"],
                                  max_batch=dep["cloud"]["max_batch"]),
+        **({"mesh": cell.mesh} if cell.mesh > 1 else {}),
         **cell.scene_fields())
     return FleetEngine(
         scn.scene, scn.detector, n_streams=scn.n_streams, trace=scn.trace,
@@ -316,21 +332,26 @@ def read_per_layer(cell: Cell, ctx: dict) -> Dict[str, dict]:
     return out
 
 
-def step_program_names(engine, rounds: int) -> dict:
-    """The compiled fleet step's module name and, per Pallas kernel, the
-    names its ``tpu_custom_call`` instructions carry in the trace (found
-    by the kernel wrapper's ``jit`` scope in their metadata)."""
-    import re
-
+def step_args(engine, rounds: int) -> tuple:
+    """The fleet step's arguments for round 0, laid out as the engine
+    lays them out: under a mesh every (S, ...) one stream-sharded."""
     import jax.numpy as jnp
-    text = engine._step.lower(
-        engine._init_state(),
-        engine._frame_inputs(engine._stacked(rounds), 0),
-        jnp.zeros((engine.n_streams,), bool), jnp.int32(0)).compile() \
-        .as_text()
-    module = re.search(r"HloModule (\S+?),", text)
+    from jax.sharding import PartitionSpec as P
+    return (engine._init_state(),
+            engine._frame_inputs(engine._stacked(rounds), 0),
+            engine._put(np.zeros(engine.n_streams, bool), P("streams")),
+            jnp.int32(0))
+
+
+def program_names(hlo_text: str) -> dict:
+    """A compiled program's module name and, per Pallas kernel, the names
+    its ``tpu_custom_call`` instructions carry in the trace (found by the
+    kernel wrapper's ``jit`` scope in their metadata, which a ``shard_map``
+    or ``named_scope`` around it leaves in place)."""
+    import re
+    module = re.search(r"HloModule (\S+?),", hlo_text)
     kernels: Dict[str, List[str]] = {}
-    for line in text.splitlines():
+    for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
             continue
         inst = re.match(r"\s*(?:ROOT )?%?(\S+) = ", line)
@@ -340,6 +361,12 @@ def step_program_names(engine, rounds: int) -> dict:
             kernels.setdefault(scope.group(1), []).append(inst.group(1))
     return {"step_module": module.group(1) if module else None,
             "kernel_ops": kernels}
+
+
+def step_program_names(engine, rounds: int) -> dict:
+    """:func:`program_names` of the engine's compiled fleet step."""
+    return program_names(
+        engine._step.lower(*step_args(engine, rounds)).compile().as_text())
 
 
 # ---------------------------------------------------------------------------
@@ -393,10 +420,39 @@ def use_compile_cache() -> str:
     return path
 
 
+def reader_shapes(cell: Cell) -> dict:
+    """The shapes one chip steps in a round: its S/M streams (a mesh of M
+    chips shards the stream axis), the sensor's sizes and the reference's
+    constants. The trace's op times are averaged over the chips, so a
+    kernel's work at these shapes over its time is one chip's share."""
+    return {"streams": cell.streams // cell.mesh, **cell.scene_fields(),
+            **reference_constants()}
+
+
+def rounds_in_trace(reduced: dict, step_module: Optional[str],
+                    chips: int) -> int:
+    """Rounds whose fleet step the trace holds: the step module's runs in
+    the traced window, per chip. The per-layer readers divide the device
+    time the trace recorded by these, not by the rounds driven: a v5e
+    trace once held 26 of 32 (every op's time 26/32 of the usual)."""
+    runs = sum(v[0] for k, v in reduced["modules"].items()
+               if step_module and (k == step_module
+                                   or k.startswith(step_module + "(")))
+    return runs // chips
+
+
 def run(workload: str, seed: int, seconds: float, trace: bool,
         t_start: float, check_device: bool = True,
         root: pathlib.Path = ROOT, log=print) -> dict:
-    """One run; returns the result object (the last line's JSON)."""
+    """One run; returns the result object (the last line's JSON).
+
+    With ``trace`` each per-layer reader gets a ``ctx`` holding the
+    untraced window's drives and seconds, the reduced trace (device times
+    averaged over the cell's chips), the step's module and kernel names,
+    ``rounds_traced`` (:func:`rounds_in_trace`, or the rounds driven under
+    the profiler where the trace holds no step) and ``shapes``
+    (:func:`reader_shapes`): the work of one chip, so that work over time
+    is a per-chip share on every layout."""
     cell = Cell(workload, root)
     if not (root / "src" / "repro").is_dir():
         raise HarnessError(f"no program under {root / 'src'}")
@@ -452,14 +508,17 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                                    n_chips=cell.chips)
         shutil.rmtree(profile_dir, ignore_errors=True)
         log(f"trace read at {time.perf_counter() - t_start:.1f} s")
+        names = step_program_names(engine, cell.rounds)
+        driven = len(traced) * cell.rounds
+        found = rounds_in_trace(reduced, names["step_module"], cell.chips)
+        log(f"the trace holds the steps of {found} of the {driven} rounds "
+            f"driven under the profiler")
         metrics = read_per_layer(cell, {
             "cell": cell, "drives": drives,
             "window_s": window_s, "trace": reduced,
             "device_kind": device["kind"],
-            "rounds_traced": len(traced) * cell.rounds,
-            "shapes": {"streams": cell.streams, **cell.scene_fields(),
-                       **reference_constants()},
-            **step_program_names(engine, cell.rounds)})
+            "rounds_traced": found or driven,
+            "shapes": reader_shapes(cell), **names})
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
     else:

@@ -1,6 +1,8 @@
 """``point_proj``'s share of its roofline, in %: the least time for the
 projection's work at the round's shapes (memory-bound at these shapes)
-over the kernel's device time per traced round."""
+over the kernel's device time per traced round. Both are one chip's: the
+shapes hold the streams one chip steps, and the trace's op times are
+averaged over the chips used."""
 from bench import roofline
 
 

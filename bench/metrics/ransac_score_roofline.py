@@ -1,7 +1,8 @@
 """``ransac_score``'s share of its roofline, in %: the least time for
 scoring every hypothesis of every cluster at the round's shapes
 (memory-bound at these shapes) over the kernel's device time per traced
-round."""
+round. Both are one chip's: the shapes hold the streams one chip steps,
+and the trace's op times are averaged over the chips used."""
 from bench import roofline
 
 

@@ -14,13 +14,17 @@ last one's end. Within it:
   chip (line ``XLA Ops``), averaged over the chips used;
 * ``device_ops``: the ten operations with the most device time, each
   named by the first characters of its HLO instruction;
-* ``idle_gaps``: the device's idle time, summed by what the host was
+* ``idle_gaps``: each chip's idle time, summed by what the host was
   doing meanwhile: inside the program's ``moby/fleet/dispatch`` or
   ``moby/fleet/fetch`` span, elsewhere inside a drive ("host loop,
-  unspanned"), or between drives;
+  unspanned"), or between drives; averaged over the chips used, as
+  ``busy_s`` is, so the gaps add up to the window less ``busy_s``;
 * ``modules``: count and device seconds of each compiled program (line
   ``XLA Modules``), and ``ops``: the same for each operation, keyed by
   its HLO instruction name.
+
+Every time is one chip's: an op's or a module's device seconds are summed
+over the chips used and divided by their number (its count is over all).
 """
 from __future__ import annotations
 
@@ -120,17 +124,17 @@ def reduce(events: dict, n_chips: int = 1) -> dict:
     w0 = min(h[1] for h in drives)
     w1 = max(h[1] + h[2] for h in drives)
     ops = [e for e in events["device"] if e[1] == OPS_LINE and e[0] < n_chips]
+    segs = _segments(host, w0, w1)
     busy_ns, gaps = 0.0, defaultdict(float)
     for chip in range(n_chips):
         mine = [(max(e[3], w0), min(e[3] + e[4], w1)) for e in ops
                 if e[0] == chip and e[3] < w1 and e[3] + e[4] > w0]
         merged = _union(mine)
         busy_ns += sum(b - a for a, b in merged)
-        if chip == 0:
-            edges = [w0] + [x for iv in merged for x in iv] + [w1]
-            idle = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
-            for label, ns in _attribute(idle, _segments(host, w0, w1)):
-                gaps[label] += ns * 1e-9
+        edges = [w0] + [x for iv in merged for x in iv] + [w1]
+        idle = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
+        for label, ns in _attribute(idle, segs):
+            gaps[label] += ns * 1e-9
     by_op: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0])
     desc: Dict[str, str] = {}
     for e in ops:
@@ -149,7 +153,7 @@ def reduce(events: dict, n_chips: int = 1) -> dict:
         "window_s": (w1 - w0) * 1e-9,
         "busy_s": busy_ns * 1e-9 / n_chips,
         "device_ops": [[desc[k], v[1]] for k, v in top],
-        "idle_gaps": sorted(([k, v] for k, v in gaps.items()),
+        "idle_gaps": sorted(([k, v / n_chips] for k, v in gaps.items()),
                             key=lambda kv: -kv[1])[:TOP],
         "ops": {k: v for k, v in by_op.items()},
         "modules": {k: v for k, v in by_module.items()},

@@ -20,11 +20,13 @@ TINY_SCENE = {"n_points": 1024, "img_h": 48, "img_w": 160, "dt": 0.1}
 def make_root(tmp: pathlib.Path, config: str = "tiny",
               traffic: str = "pair", metric: str = None,
               sensor: dict = None, streams: int = 2,
-              rounds: int = 4, scene_seed: int = 2302) -> pathlib.Path:
+              rounds: int = 4, scene_seed: int = 2302, mesh: int = None,
+              chips: int = 1) -> pathlib.Path:
     """A checkout-like directory holding a one-cell BENCHMARK.json, the
-    cell's configuration, traffic mix and limits as files, the metric
-    readers (plus ``metric``, if given, as a reader of its own) and the
-    program. The cell is ``<config>.<traffic>``."""
+    cell's configuration (with ``"mesh": {"streams": mesh}`` if given),
+    traffic mix and limits as files, the metric readers (plus ``metric``,
+    if given, as a reader of its own) and the program. The cell is
+    ``<config>.<traffic>`` on ``chips`` chips."""
     real = json.loads((ROOT / "BENCHMARK.json").read_text())
     for d in ("configs", "traffic", "limits", "metrics"):
         (tmp / "bench" / d).mkdir(parents=True, exist_ok=True)
@@ -36,6 +38,8 @@ def make_root(tmp: pathlib.Path, config: str = "tiny",
     cfg["name"] = config
     cfg["sensor"] = dict(sensor or TINY_SCENE)
     cfg["scene"] = {"max_obj": 6, "density_scale": 4000.0}
+    if mesh is not None:
+        cfg["mesh"] = {"streams": mesh}
     (tmp / "bench" / "configs" / f"{config}.json").write_text(json.dumps(cfg))
     (tmp / "bench" / "traffic" / f"{traffic}.json").write_text(json.dumps(
         {"name": traffic, "streams": streams, "rounds_per_drive": rounds,
@@ -57,7 +61,7 @@ def make_root(tmp: pathlib.Path, config: str = "tiny",
                                 "file": f"bench/configs/{config}.json",
                                 "reduced": [], "why": "test"}],
                 workloads=[{"name": cell, "config": config,
-                            "traffic": traffic, "chips": 1,
+                            "traffic": traffic, "chips": chips,
                             "why": "test"}],
                 per_layer=per_layer)
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
