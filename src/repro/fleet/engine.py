@@ -216,7 +216,8 @@ class FleetEngine:
 
     def _put(self, a: np.ndarray, spec: P) -> jax.Array:
         """Host array -> device. Under a mesh it goes straight into its
-        ``spec`` shards instead of landing whole on device 0 first."""
+        ``spec`` shards (``P()``: a copy on every chip) instead of landing
+        whole on device 0 first."""
         if self.mesh is None:
             return jnp.asarray(a)
         return jax.device_put(a, NamedSharding(self.mesh, spec))
@@ -233,8 +234,11 @@ class FleetEngine:
 
         Observed, each round is a ``fleet/round`` span whose phases are
         its children (``parent`` = the round's index): ``fleet/inputs``
-        (the host->device puts, with their ``bytes`` and ``puts``),
-        ``fleet/telemetry``, ``fleet/dispatch``, ``fleet/fetch`` and
+        (every host->device put of the round: the tape's frame, the test
+        arrivals and the round index, with their ``puts``, the ``bytes``
+        that land on the chips, the ``chips`` they are split over and
+        ``bytes_per_chip``), ``fleet/telemetry``, ``fleet/dispatch`` (the
+        launch alone), ``fleet/fetch`` (with its ``chips``) and
         ``fleet/contention`` (uplink, cloud pool and latency bookkeeping,
         with the round's ``senders``); ``fleet/prologue`` and
         ``fleet/epilogue`` hold the run's set-up and report."""
@@ -249,8 +253,12 @@ class FleetEngine:
             stack = self._stacked(n_frames)
             want_audit = obs is not None and obs.cfg.want_audit
             if obs is not None:
-                # Bytes of one round's puts (the same every round).
-                in_bytes = sum(a[:, 0].nbytes for a in stack)
+                # Bytes one round's puts land on the chips (the same every
+                # round): the stream-sharded frame and arrivals once, the
+                # replicated round index on every chip.
+                n_puts = len(stack) + 2
+                in_bytes = sum(a[:, 0].nbytes for a in stack) + s_n \
+                    + np.dtype(np.int32).itemsize * self.n_shards
             self.batcher.sink = obs
             state = self._init_state()
             edge_inf = self._edge_infer()   # (S,), frame-invariant
@@ -264,11 +272,16 @@ class FleetEngine:
         for t in range(n_frames):
             with obs.measured_span("fleet/round", frame=t) \
                     if obs is not None else _NULL_CTX:
+                # Set by the previous round's contention.
+                arrived = walls >= inflight_at
                 with obs.measured_span(
                         "fleet/inputs", parent=t, frame=t, bytes=in_bytes,
-                        puts=len(stack)) if obs is not None else _NULL_CTX:
+                        puts=n_puts, chips=self.n_shards,
+                        bytes_per_chip=in_bytes // self.n_shards) \
+                        if obs is not None else _NULL_CTX:
                     inp = self._frame_inputs(stack, t)
-                arrived = walls >= inflight_at
+                    arrived_d = self._put(arrived, P("streams"))
+                    t_d = self._put(np.int32(t), P())
                 pre_tel = None
                 with obs.measured_span("fleet/telemetry", parent=t,
                                        frame=t) if obs is not None \
@@ -284,10 +297,9 @@ class FleetEngine:
                 with obs.measured_span("fleet/dispatch", jit_fn=self._step,
                                        parent=t, frame=t) \
                         if obs is not None else _NULL_CTX:
-                    state, packed = self._step(
-                        state, inp, self._put(arrived, P("streams")),
-                        jnp.int32(t))
-                with obs.measured_span("fleet/fetch", parent=t, frame=t) \
+                    state, packed = self._step(state, inp, arrived_d, t_d)
+                with obs.measured_span("fleet/fetch", parent=t, frame=t,
+                                       chips=self.n_shards) \
                         if obs is not None else _NULL_CTX:
                     pk = np.asarray(packed)        # the one fetch per frame
                 with obs.measured_span("fleet/contention", parent=t,

@@ -258,6 +258,16 @@ class Observer:
                         shard=k)
                 ns.set(lat.shape[1], scenario=report.scenario,
                        policy=report.policy, shard=k)
+        inputs = [r for r in self.measured if r["name"] == "fleet/inputs"]
+        if inputs:
+            # The fleet rounds' host->device puts, by the mesh shard they
+            # land on (one shard without a mesh).
+            c = reg.counter("moby_fleet_input_bytes_total",
+                            "bytes the fleet rounds put on each mesh shard",
+                            labels=("shard",))
+            per_shard = sum(r["bytes_per_chip"] for r in inputs)
+            for k in range(self.n_shards):
+                c.inc(per_shard, shard=k)
         if self.bytes_up or self.bytes_down:
             c = reg.counter("moby_uplink_bytes_total",
                             "modeled bytes over the shared cell",

@@ -1,11 +1,13 @@
 """The fleet round's host spans and the device step's named stages.
 
 Observed (``ObsConfig(trace=True)``), ``FleetEngine.run`` records one
-``fleet/round`` span per round with its five phases as children, a
+``fleet/round`` span per round with its five phases as children (every
+host->device put of a round inside its ``fleet/inputs``), a
 ``fleet/prologue`` and a ``fleet/epilogue`` per run, each on one
 process-wide clock (``t_ns``) with the run's id. The compiled step names
 every stage of the device work in its entry instructions' ``op_name``.
 """
+import contextlib
 import re
 
 import jax
@@ -91,8 +93,56 @@ def test_inputs_span_counts_the_bytes_and_puts_of_the_round(engine, drives):
     for spans in drives:
         for s in _named(spans, "fleet/inputs"):
             inp = engine._frame_inputs(stack, s["frame"])
-            assert s["bytes"] == sum(int(a.nbytes) for a in inp)
-            assert s["puts"] == len(inp)
+            # the tape's frame, then the (S,) test arrivals and the int32
+            # round index
+            assert s["bytes"] == sum(int(a.nbytes) for a in inp) \
+                + engine.n_streams + 4
+            assert s["puts"] == len(inp) + 2
+
+
+def test_on_one_device_the_round_puts_ten_arrays_on_one_chip(drives):
+    for spans in drives:
+        for s in _named(spans, "fleet/inputs"):
+            assert (s["puts"], s["chips"]) == (10, 1)
+            assert s["bytes_per_chip"] == s["bytes"]
+        assert all(s["chips"] == 1 for s in _named(spans, "fleet/fetch"))
+
+
+def test_every_put_of_a_round_happens_inside_its_inputs_span(engine,
+                                                            monkeypatch):
+    open_spans, seen = [], []
+    measured_span = obs.Observer.measured_span
+    put = FleetEngine._put
+
+    @contextlib.contextmanager
+    def tracked(self, name, *a, **kw):
+        open_spans.append(name)
+        try:
+            with measured_span(self, name, *a, **kw) as extra:
+                yield extra
+        finally:
+            open_spans.pop()
+
+    def recorded(self, a, spec):
+        seen.append(list(open_spans))
+        return put(self, a, spec)
+
+    monkeypatch.setattr(obs.Observer, "measured_span", tracked)
+    monkeypatch.setattr(FleetEngine, "_put", recorded)
+    engine.run(FRAMES)
+    assert seen == [["fleet/round", "fleet/inputs"]] * (10 * FRAMES)
+
+
+def test_input_bytes_counter_sums_the_rounds_puts_per_shard():
+    reg = obs.MetricsRegistry()
+    report = FleetEngine(_cfg(), "oracle", n_streams=2, seed=3,
+                         obs=obs.ObsConfig(metrics=True, registry=reg)
+                         ).run(FRAMES)
+    report.obs.flush_metrics(report)
+    inputs = _named(report.obs.measured, "fleet/inputs")
+    c = reg.counter("moby_fleet_input_bytes_total", labels=("shard",))
+    assert [k for k, _ in c.samples()] == [("0",)]
+    assert c.value(shard=0) == sum(s["bytes"] for s in inputs)
 
 
 def test_contention_span_counts_the_round_senders(drives):

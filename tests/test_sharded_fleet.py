@@ -10,7 +10,10 @@ FleetEngine / Session.
   length and fleet size (runtime.hlo_analysis).
 * **Multi-device parity** — a subprocess with 4 virtual CPU devices
   (``--xla_force_host_platform_device_count`` must precede JAX init)
-  checks sharded == unsharded bitwise with real cross-shard psum.
+  checks sharded == unsharded bitwise with real cross-shard psum, and
+  that an observed orchestrated run puts each round's inputs over the
+  four chips (``fleet/inputs``' ``chips`` and ``bytes_per_chip``, the
+  per-shard input-bytes counter) without a retrace.
 * **API routing** — Scenario.mesh reaches the engine; "auto" degrades to
   the unsharded path on a 1-device host; per-shard observer metrics.
 """
@@ -127,6 +130,43 @@ class TestMultiDevice:
             assert np.array_equal(a, b), np.abs(a - b).max()
             assert np.array_equal(ka, kb)
             print("SHARDED_PARITY_OK")
+
+            # Observed orchestrated rounds: every put of a round is
+            # split over the four chips, and the per-shard counter adds
+            # up to what the rounds put.
+            from repro.obs import MetricsRegistry, ObsConfig
+            reg = MetricsRegistry()
+            e = FleetEngine(cfg, "pointpillar", n_streams=8, seed=0, mesh=4,
+                            obs=ObsConfig(trace=True, metrics=True,
+                                          registry=reg))
+            compiles = []
+            jax.monitoring.register_event_duration_secs_listener(
+                lambda ev, d, **kw: compiles.append(ev)
+                if ev == "/jax/core/compile/backend_compile_duration"
+                else None)
+            runs = []
+            for _ in range(2):
+                compiles.clear()
+                runs.append(e.run(3))
+            spans = [s for r in runs for s in r.obs.measured]
+            inputs = [s for s in spans if s["name"] == "fleet/inputs"]
+            assert len(inputs) == 6
+            for s in inputs:
+                assert (s["puts"], s["chips"]) == (10, 4), s
+                assert s["bytes_per_chip"] * 4 == s["bytes"], s
+            assert all(s["chips"] == 4 for s in spans
+                       if s["name"] == "fleet/fetch")
+            # The committed, replicated round index matches the step's
+            # P() in-sharding: a warm engine's run compiles nothing.
+            assert compiles == [], compiles
+            for r in runs:
+                r.obs.flush_metrics(r)
+            c = reg.counter("moby_fleet_input_bytes_total", labels=("shard",))
+            total = sum(s["bytes"] for s in inputs)
+            assert [k for k, _ in c.samples()] == [("0",), ("1",), ("2",),
+                                                   ("3",)]
+            assert all(v * 4 == total for _, v in c.samples())
+            print("SHARDED_INPUTS_OK")
         """)
         env = dict(os.environ,
                    XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
@@ -139,6 +179,7 @@ class TestMultiDevice:
                                  os.path.abspath(__file__))))
         assert out.returncode == 0, out.stderr[-2000:]
         assert "SHARDED_PARITY_OK" in out.stdout
+        assert "SHARDED_INPUTS_OK" in out.stdout
 
 
 class TestApiRouting:
