@@ -104,6 +104,10 @@ def build_clusters(points: jnp.ndarray, labels: jnp.ndarray, max_obj: int,
                    pts_per_obj: int):
     """Compact labeled points into fixed per-object buffers.
 
+    Slot ``j`` holds the points labeled ``j + 1``, in index order: its
+    first ``min(count, P)`` rows are the lowest-indexed members, and every
+    row beyond ``valid`` is zero. Labels outside ``1..O`` belong to no slot.
+
     Args:
       points: (N, 3).
       labels: (N,) int32 instance ids (0 = background).
@@ -111,18 +115,40 @@ def build_clusters(points: jnp.ndarray, labels: jnp.ndarray, max_obj: int,
       pts_per_obj: P, buffer size per object.
 
     Returns:
-      clusters: (O, P, 3) point buffers (zeros beyond valid).
-      valid: (O, P) bool masks.
+      clusters: (O, min(P, N), 3) point buffers (zeros beyond valid).
+      valid: (O, min(P, N)) bool masks.
       counts: (O,) number of points per object (possibly > P before capping).
     """
-    def one(obj_id):
-        m = labels == obj_id
-        order = jnp.argsort(~m)  # members first, stable
-        idx = order[:pts_per_obj]
-        v = m[idx]
-        pts = jnp.where(v[:, None], points[idx], 0.0)
-        return pts, v, jnp.sum(m)
+    return _build_clusters(points, labels, max_obj, pts_per_obj,
+                           _packed_key_fits(labels.shape[0], max_obj))
+
+
+def _packed_key_fits(n: int, max_obj: int) -> bool:
+    """Whether slot key and point index share one non-negative int32."""
+    return (max_obj + 1) << (n - 1).bit_length() < 2 ** 31
+
+
+def _build_clusters(points, labels, max_obj, pts_per_obj, packed: bool):
+    # One sort per stream by slot key puts each slot's members in one
+    # contiguous run, in index order; the background key O + 1 sorts last.
+    # Packed, the index in the low bits breaks ties, so one operand sorts
+    # with no stability flag.
+    n = labels.shape[0]
+    ib = (n - 1).bit_length()
+    key = jnp.where((labels >= 1) & (labels <= max_obj), labels,
+                    max_obj + 1).astype(jnp.int32)
+    iota = jnp.arange(n, dtype=jnp.int32)
+    if packed:
+        order = jax.lax.sort((key << ib) | iota, is_stable=False)
+        order = order & ((1 << ib) - 1)
+    else:
+        _, order = jax.lax.sort((key, iota), num_keys=1, is_stable=True)
 
     obj_ids = jnp.arange(1, max_obj + 1, dtype=jnp.int32)
-    clusters, valid, counts = jax.vmap(one)(obj_ids)
+    counts = jnp.sum(labels[None, :] == obj_ids[:, None], axis=1)
+    starts = jnp.cumsum(counts) - counts
+    k = jnp.arange(min(pts_per_obj, n), dtype=jnp.int32)
+    idx = order[jnp.clip(starts[:, None] + k, 0, n - 1)]
+    valid = k < counts[:, None]
+    clusters = jnp.where(valid[..., None], points[idx], 0.0)
     return clusters, valid, counts
