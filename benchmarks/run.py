@@ -29,7 +29,6 @@ MODULES = [
     "fig15_breakdown",
     "fig16_sensitivity",
     "fig17_efficiency",
-    "fleet_scaling",
     "kernel_backends",
     "sweep",
     "roofline",

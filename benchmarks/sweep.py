@@ -13,7 +13,7 @@ row per (scenario, policy) cell.
 
 ``--smoke`` is the CI entry point: one lean scenario, two policies, a
 handful of frames. ``--fleet`` sweeps the fleet presets (S=16 congested,
-S=64 heterogeneous) in single-dispatch scan mode and emits per-device
+S=64 heterogeneous) through ``Session.run`` and emits per-device
 p95 modeled latency beside the per-frame CSV rows (whose ``device``
 column carries each stream's profile). Also registered in
 ``benchmarks.run`` (module name ``sweep``) with a small default grid.
@@ -33,28 +33,27 @@ from repro.launch.compile_cache import use_compile_cache
 SCENARIOS = ("kitti-urban", "sparse-lidar", "dense-traffic", "lossy-uplink")
 POLICIES = ("fos", "periodic(4)", "periodic(8)", "adaptive")
 
-# Fleet grid (--fleet): S x device-mix x GPU-pool, scan mode.
+# Fleet grid (--fleet): S x device-mix x GPU-pool.
 FLEET_SCENARIOS = ("fleet-16-congested", "fleet-64-mixed")
 FLEET_POLICIES = ("fos", "adaptive")
 
 
 def sweep(scenarios: Sequence[str] = SCENARIOS,
           policies: Sequence[str] = POLICIES, frames: int = 32,
-          seed: int = 0, out: Optional[str] = None, scan: bool = False,
+          seed: int = 0, out: Optional[str] = None,
           obs: Optional[api.ObsConfig] = None) -> Tuple[str, List[Dict]]:
     """Run the grid; returns (csv_text, per-cell summary dicts) and
-    optionally writes the CSV to ``out``. ``scan=True`` serves each cell
-    through the fleet's single-dispatch ``lax.scan`` mode (fleet grids).
-    ``obs`` turns on repro.obs for every cell — each run exports its own
-    trace/audit ({n}/{scenario}/{policy} path placeholders), metrics
-    accumulate across the grid into one exposition."""
+    optionally writes the CSV to ``out``. ``obs`` turns on repro.obs for
+    every cell — each run exports its own trace/audit
+    ({n}/{scenario}/{policy} path placeholders), metrics accumulate
+    across the grid into one exposition."""
     parts: List[str] = []
     summaries: List[Dict] = []
     for scn_name in scenarios:
         for policy in policies:
             sess = api.Session(api.scenario(scn_name, policy=policy,
                                             seed=seed), obs=obs)
-            rep = sess.run(frames, scan=scan)
+            rep = sess.run(frames)
             parts.append(rep.to_csv(header=not parts))
             s = rep.summary()
             summaries.append(s)
@@ -108,7 +107,7 @@ def main() -> None:
                     help="CI smoke: lean scenario, two policies, 8 frames")
     ap.add_argument("--fleet", action="store_true",
                     help="fleet grid: congested + heterogeneous presets, "
-                         "scan mode, per-device p95 emits")
+                         "per-device p95 emits")
     from benchmarks.common import add_obs_args, obs_from_args
     add_obs_args(ap)
     args = ap.parse_args()
@@ -121,7 +120,7 @@ def main() -> None:
     elif args.fleet:
         text, _ = sweep(scenarios=FLEET_SCENARIOS, policies=FLEET_POLICIES,
                         frames=args.frames, seed=args.seed,
-                        out=args.out, scan=True, obs=obs)
+                        out=args.out, obs=obs)
     else:
         text, _ = sweep(scenarios=args.scenarios, policies=args.policies,
                         frames=args.frames, seed=args.seed, out=args.out,

@@ -1,12 +1,12 @@
 """Bring-up smoke of the fleet serving path on a TPU.
 
-Drives ``api.Session(...).run`` — the orchestrated loop and the
-one-dispatch scan — for a 16-stream ``kitti-urban`` fleet at the KITTI
-sensor's size (an HDL-64E sweep of 120k points and one 1242x375 camera),
-on the chip's default ops backend: the Pallas kernels, compiled by Mosaic.
-The same session on the ``ref`` backend, once on the chip and once pinned
-to the host CPU, is the reference: frame kinds must match exactly and
-F1/precision/recall within the golden CSVs' tolerance.
+Drives ``api.Session(...).run`` — the orchestrated loop — for a 16-stream
+``kitti-urban`` fleet at the KITTI sensor's size (an HDL-64E sweep of 120k
+points and one 1242x375 camera), on the chip's default ops backend: the
+Pallas kernels, compiled by Mosaic. The same session on the ``ref``
+backend, once on the chip and once pinned to the host CPU, is the
+reference: frame kinds must match exactly and F1/precision/recall within
+the golden CSVs' tolerance.
 
     python chip_smoke.py             # one chip
     python chip_smoke.py --chips 4   # only the sharded phase: a 4-chip
@@ -101,53 +101,46 @@ def compare(got, want, what: str, bitwise: bool = False) -> list:
 
 
 def serve(scn, n_frames: int, label: str, repeat: bool = True):
-    """One Session through ``run`` and then ``run(scan=True)``. With
-    ``repeat`` each path runs a second time: the steady (compiled, tape
-    recorded) wall time, which must reproduce the first run bitwise.
-    Returns (session, {"run": report, "scan": report}, problems)."""
+    """One Session through ``run``. With ``repeat`` it runs a second time:
+    the steady (compiled, tape recorded) wall time, which must reproduce
+    the first run bitwise. Returns (session, {"run": report}, problems)."""
     from repro import api
     sess = api.Session(scn)
-    reports, problems = {}, []
+    problems = []
+    what = f"{label}/run"
     with CompileClock() as clock:
-        for path in ("run", "scan"):
-            what = f"{label}/{path}"
-            c0, t0 = clock.seconds, time.perf_counter()
-            first = sess.run(n_frames, scan=path == "scan")
-            t1, c1 = time.perf_counter(), clock.seconds
-            rec = {"phase": what, "backend": sess.engine.tparams.backend,
-                   "streams": scn.n_streams, "frames": n_frames,
-                   "first_s": t1 - t0, "compile_s": c1 - c0}
-            if repeat:
-                again = sess.run(n_frames, scan=path == "scan")
-                rec["steady_s"] = time.perf_counter() - t1
-                p = compare(again, first, f"{what} repeat", bitwise=True)
-                rec["repeat_bitwise"] = not p
-                problems += p
-            emit(rec)
-            problems += check_report(first, scn.n_streams, n_frames, what)
-            reports[path] = first
-    return sess, reports, problems
+        t0 = time.perf_counter()
+        first = sess.run(n_frames)
+        t1 = time.perf_counter()
+        rec = {"phase": what, "backend": sess.engine.tparams.backend,
+               "streams": scn.n_streams, "frames": n_frames,
+               "first_s": t1 - t0, "compile_s": clock.seconds}
+        if repeat:
+            again = sess.run(n_frames)
+            rec["steady_s"] = time.perf_counter() - t1
+            p = compare(again, first, f"{what} repeat", bitwise=True)
+            rec["repeat_bitwise"] = not p
+            problems += p
+    emit(rec)
+    problems += check_report(first, scn.n_streams, n_frames, what)
+    return sess, {"run": first}, problems
 
 
-def custom_calls(engine, n_frames: int) -> dict:
-    """``tpu_custom_call`` (Pallas kernel) count of the compiled fleet step
-    and of the compiled scan."""
+def custom_calls(engine, n_frames: int) -> int:
+    """``tpu_custom_call`` (Pallas kernel) count of the compiled fleet
+    step."""
     import jax.numpy as jnp
     marker = 'custom_call_target="tpu_custom_call"'
     step = engine._step.lower(
         engine._init_state(),
         engine._frame_inputs(engine._stacked(n_frames), 0),
         jnp.zeros((engine.n_streams,), bool), jnp.int32(0)).compile()
-    fn, consts = engine._scan_fn()
-    scan = fn.lower(consts, engine._init_state(),
-                    engine._scan_inputs(n_frames), n_frames).compile()
-    return {"step": step.as_text().count(marker),
-            "scan": scan.as_text().count(marker)}
+    return step.as_text().count(marker)
 
 
 def chip_checks(engine, n_frames: int) -> list:
-    """The engine serves the Pallas backend, and its compiled programs
-    hold the kernels."""
+    """The engine serves the Pallas backend, and its compiled step holds
+    the kernels."""
     import jax
     backend = engine.tparams.backend
     calls = custom_calls(engine, n_frames)
@@ -159,8 +152,8 @@ def chip_checks(engine, n_frames: int) -> list:
     if backend != "pallas":
         problems.append(f"default backend resolved to {backend!r}, "
                         f"not 'pallas'")
-    if not all(calls.values()):
-        problems.append(f"no Pallas kernel in a compiled program: {calls}")
+    if not calls:
+        problems.append("no Pallas kernel in the compiled step")
     return problems
 
 
@@ -172,26 +165,24 @@ def reference_phase(chip: dict, scn_ref, n_frames: int, cpu_device) -> list:
     with jax.default_device(cpu_device):
         _, ref_cpu, p = serve(scn_ref, n_frames, "A/cpu-ref", repeat=False)
     problems += p
-    for path in ("run", "scan"):
-        problems += compare(chip[path], ref_cpu[path],
-                            f"A/{path}: chip-default vs cpu-ref")
-        problems += compare(chip[path], ref_chip[path],
-                            f"A/{path}: chip-default vs chip-ref")
+    problems += compare(chip["run"], ref_cpu["run"],
+                        "A/run: chip-default vs cpu-ref")
+    problems += compare(chip["run"], ref_chip["run"],
+                        "A/run: chip-default vs chip-ref")
     return problems
 
 
 def sharded_phase(scn, n_devices: int, n_frames: int) -> list:
     """The fleet sharded over ``n_devices`` against the same fleet on one
-    device, through ``run`` and ``run(scan=True)``: bit for bit."""
+    device, through ``run``: bit for bit."""
     import dataclasses
     _, one, problems = serve(scn, n_frames, "S/mesh=None", repeat=False)
     _, many, p = serve(dataclasses.replace(scn, mesh=n_devices), n_frames,
                        f"S/mesh={n_devices}", repeat=False)
     problems += p
-    for path in ("run", "scan"):
-        problems += compare(many[path], one[path],
-                            f"S/{path}: mesh={n_devices} vs mesh=None",
-                            bitwise=True)
+    problems += compare(many["run"], one["run"],
+                        f"S/run: mesh={n_devices} vs mesh=None",
+                        bitwise=True)
     return problems
 
 

@@ -41,7 +41,6 @@ class Session:
         devices = scn.stream_devices()  # fail fast on unknown devices
         if scn.n_streams < 1:
             raise ValueError(f"n_streams must be >= 1, got {scn.n_streams}")
-        self._scan_engine = None
         # Baselines (edge_only/cloud_only) are single-stream notions — a
         # fleet preset's baseline comparison runs on one stream rather
         # than rejecting the mode (FleetEngine serves moby modes only).
@@ -55,43 +54,22 @@ class Session:
                 comp=scn.comp, backend=scn.backend, device=devices[0],
                 obs=obs)
         else:
-            self.engine = self._scan_engine = self._fleet(scn.n_streams)
-
-    def _fleet(self, n_streams: int) -> FleetEngine:
-        scn = self.scenario
-        # A lazily built S=1 slice of a fleet scenario keeps stream 0's
-        # resolved device; full-size fleets pass the spec through.
-        device = scn.device if n_streams == scn.n_streams \
-            else list(scn.stream_devices()[:n_streams])
-        return FleetEngine(
-            scn.scene, scn.detector, n_streams=n_streams, trace=scn.trace,
-            mode=scn.mode, use_fos=scn.use_fos, use_tba=scn.use_tba,
-            tparams=scn.tparams, sparams=scn.scheduler_params(),
-            seed=scn.seed, comp=scn.comp,
-            cloud_cfg=scn.cloud, backend=scn.backend, device=device,
-            obs=self.obs,
-            # Lazy S=1 slices (scan-mode baselines) stay unsharded — a
-            # fleet mesh sized for scn.n_streams won't divide 1 stream.
-            mesh=scn.mesh if n_streams == scn.n_streams else None)
+            self.engine = FleetEngine(
+                scn.scene, scn.detector, n_streams=scn.n_streams,
+                trace=scn.trace, mode=scn.mode, use_fos=scn.use_fos,
+                use_tba=scn.use_tba, tparams=scn.tparams, sparams=sparams,
+                seed=scn.seed, comp=scn.comp, cloud_cfg=scn.cloud,
+                backend=scn.backend, device=scn.device, obs=obs,
+                mesh=scn.mesh)
 
     @property
     def n_streams(self) -> int:
         """Streams the built engine actually serves (1 for baselines)."""
         return getattr(self.engine, "n_streams", 1)
 
-    def run(self, n_frames: int, scan: bool = False) -> RunReport:
-        """Serve ``n_frames`` per stream.
-
-        ``scan=True`` uses the fleet's single-dispatch ``lax.scan`` mode
-        (benchmark timing). At S=1 an equivalent single-stream fleet slice
-        is built lazily for it (S=1 fleet parity is a tested invariant).
-        """
-        if scan:
-            if self._scan_engine is None:
-                self._scan_engine = self._fleet(1)
-            report = self._scan_engine.run_scan(n_frames)
-        else:
-            report = self.engine.run(n_frames)
+    def run(self, n_frames: int) -> RunReport:
+        """Serve ``n_frames`` per stream."""
+        report = self.engine.run(n_frames)
         report.scenario = self.scenario.name
         report.policy = self.scenario.scheduler_params().policy \
             if self.scenario.use_fos else ""

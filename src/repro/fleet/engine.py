@@ -19,22 +19,16 @@ profile name, a per-stream list, or a mix spec, stacked into a
 and scheduler cost telemetry (a uniform vector reproduces the scalar
 ``device=`` path bitwise; tests/test_heterogeneity.py).
 
-Two run modes:
-
-* :meth:`FleetEngine.run` — orchestrated: one jitted dispatch + one packed
-  stats fetch per frame for the whole fleet, byte-accurate netsim timing.
-* :meth:`FleetEngine.run_scan` — benchmark: the entire run is one
-  ``lax.scan`` dispatch with an on-device network/cloud approximation.
-
-With S=1 both the inputs (serving.tape) and the timing reduce exactly to
-the single-stream ``MobyEngine`` — enforced by tests/test_fleet.py.
+:meth:`FleetEngine.run` serves the fleet: one jitted dispatch and one
+packed stats fetch per frame for the whole fleet, with byte-accurate
+netsim timing on the host. With S=1 both the inputs (serving.tape) and
+the timing reduce exactly to the single-stream ``MobyEngine`` — enforced
+by tests/test_fleet.py.
 
 Sharded megafleet: ``mesh=`` (None / ``"auto"`` / a device count / a 1-D
 ``streams`` Mesh from ``launch.mesh.make_fleet_mesh``) partitions the
-stream axis of every carry/tape buffer across devices. The orchestrated
-step is embarrassingly parallel (contention stays host-global); the scan
-twin runs per shard under ``shard_map`` with the round's sender count
-``psum``-ed so uplink shares and GPU-pool queueing stay fleet-global. A
+stream axis of every carry and input buffer across devices. The step is
+embarrassingly parallel: contention stays on the host, fleet-global. A
 1-device mesh reproduces the unsharded path bitwise
 (tests/test_sharded_fleet.py).
 """
@@ -105,7 +99,6 @@ class FleetEngine:
         self.cfg = scene_cfg
         self.detector = detector
         self.n_streams = n_streams
-        self.trace = trace
         self.mode = mode
         self.use_fos = use_fos
         self.use_tba = use_tba
@@ -115,8 +108,8 @@ class FleetEngine:
         # per-stream. The cloud side stays on the 2080Ti profile.
         self.pvec = profiles.profile_vector(device, n_streams)
         self.stream_devices = self.pvec.names
-        # Stacked (S,)-field component model for the scan/telemetry paths
-        # (an explicitly passed scalar `comp` broadcasts to every stream),
+        # Stacked (S,)-field component model for the telemetry (an
+        # explicitly passed scalar `comp` broadcasts to every stream),
         # plus per-stream scalar slices for the host loop.
         self.comp = comp or profiles.component_times_vector(self.pvec)
         self.comps = [profiles.component_slice(self.comp, s)
@@ -158,10 +151,9 @@ class FleetEngine:
         self.obs_config = obs
         self._given_tapes = list(tapes) if tapes is not None else None
         self._stack: Optional[tape_lib.FrameTape] = None
-        self._scan_cache = None
         # Stream-axis device mesh (launch.mesh): None / "auto" / a device
-        # count / a ready Mesh. Resolved once; both run modes shard every
-        # (S, ...) buffer along it and keep contention fleet-global.
+        # count / a ready Mesh. Resolved once; the step shards every
+        # (S, ...) buffer along it and contention stays fleet-global.
         self.mesh = mesh_lib.resolve_fleet_mesh(mesh, n_streams)
         self.n_shards = 1 if self.mesh is None \
             else int(self.mesh.devices.size)
@@ -393,73 +385,3 @@ class FleetEngine:
             state = jax.device_put(
                 state, NamedSharding(self.mesh, P("streams")))
         return state
-
-    def run_scan(self, n_frames: int) -> RunReport:
-        """Benchmark mode: the whole fleet run is ONE ``lax.scan`` dispatch,
-        with the network/cloud model evaluated on device.
-
-        Observability: metrics and the (array-reconstructed) trace work;
-        the scheduler audit needs the orchestrated :meth:`run` — scan mode
-        keeps the telemetry on device, and auditing it would mean exactly
-        the per-frame fetches scan mode exists to avoid."""
-        obs = obs_lib.make_observer(
-            self.obs_config, n_streams=self.n_streams,
-            devices=self.stream_devices,
-            policy=self.sparams.policy if self.use_fos else "",
-            detector=self.detector, frame_dt=self.frame_dt,
-            n_shards=self.n_shards)
-        if obs is not None and obs.cfg.want_audit:
-            raise ValueError(
-                "ObsConfig(audit=...) requires the orchestrated "
-                "FleetEngine.run(); scan mode keeps the scheduler "
-                "telemetry on device")
-        fn, consts = self._scan_fn()
-        with obs.measured_span("fleet/scan_dispatch", jit_fn=fn,
-                               n_frames=n_frames) if obs is not None \
-                else _NULL_CTX:
-            state, outs = fn(consts, self._init_state(),
-                             self._scan_inputs(n_frames), n_frames)
-        with obs.measured_span("fleet/scan_fetch") if obs is not None \
-                else _NULL_CTX:
-            packed = np.asarray(outs).transpose(1, 0, 2)  # (F,S,C)->(S,F,C)
-        report = report_from_packed(packed, devices=self.stream_devices)
-        report.frame_dt = self.frame_dt
-        if obs is not None:
-            obs.finalize(report)
-        return report
-
-    def _scan_inputs(self, n_frames: int) -> step_lib.FrameInputs:
-        stack = self._stacked(n_frames)
-        # (S, F, ...) -> (F, S, ...) device arrays for scan's leading axis;
-        # under a mesh the tape lands stream-sharded (axis 1) up front, so
-        # the scan dispatch never re-lays-out the largest buffers.
-        return step_lib.FrameInputs(
-            *(self._put(a.swapaxes(0, 1), P(None, "streams"))
-              for a in stack))
-
-    def _scan_fn(self):
-        if self._scan_cache is not None:
-            return self._scan_cache
-        net = step_lib.ScanNetParams(
-            bw_mbps=jnp.asarray(netsim.synthesize_trace(self.trace,
-                                                        seed=self.seed),
-                                jnp.float32),
-            trace_dt=0.1, rtt_s=self.uplink.rtt_s, frame_dt=self.frame_dt,
-            pc_mbits=PC_BYTES * 8 / 1e6,
-            result_mbits=RESULT_BYTES * 8 / 1e6,
-            infer_s=self.cloud_cfg.infer_s,
-            marginal=self.cloud_cfg.marginal,
-            max_batch=self.cloud_cfg.max_batch,
-            n_gpus=self.cloud_cfg.n_gpus,
-            # Mirrored batch window: round batching already satisfies any
-            # window (a round's requests arrive at one modeled instant, so
-            # a window never splits it — tests/test_cloud_multigpu.py
-            # proves host/scan agreement under a configured window).
-            window_s=self.cloud_cfg.window_s)
-        self._scan_cache = step_lib.make_fleet_scan(
-            self.n_streams, self.calib, self.tparams, self.sparams,
-            self.comp, net, self.use_fos,
-            onboard_anchors=self.mode == "moby_onboard",
-            edge_infer_s=self._edge_infer(),
-            charge_fos=self._charge_fos, mesh=self.mesh)
-        return self._scan_cache

@@ -44,7 +44,7 @@ def make_fleet_mesh(n_devices: Optional[int] = None):
     ``n_devices=None`` uses every available device. An explicit count is
     validated against the host (multi-device CPU hosts come from
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``, set before JAX
-    initializes — see benchmarks/fleet_scaling.py ``--devices``).
+    initializes — see tests/test_sharded_fleet.py's subprocess).
     """
     avail = len(jax.devices())
     n = avail if n_devices is None else int(n_devices)

@@ -185,7 +185,7 @@ class ProfileVector:
     modeled-latency arithmetic is elementwise-identical to the scalar
     :class:`DeviceProfile` path — a uniform vector reproduces the scalar
     results bitwise). The arrays are plain numpy: usable directly inside
-    jitted / ``lax.scan`` steps, where they enter the trace as constants.
+    jitted steps, where they enter the trace as constants.
     """
     profiles: Tuple[DeviceProfile, ...]
     names: Tuple[str, ...]

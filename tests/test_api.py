@@ -48,14 +48,6 @@ class TestSessionParity:
         np.testing.assert_array_equal(rep.f1, direct.f1)
         np.testing.assert_array_equal(rep.latency_s, direct.latency_s)
 
-    def test_scan_mode_matches_orchestrated_decisions(self):
-        sess = api.Session(api.scenario("smoke", seed=5, n_streams=2))
-        orch = sess.run(FRAMES)
-        scan = sess.run(FRAMES, scan=True)
-        for s in range(2):
-            assert orch.kinds(s) == scan.kinds(s)
-        np.testing.assert_allclose(orch.f1, scan.f1, atol=1e-5)
-
     def test_string_scenario_and_engine_choice(self):
         assert isinstance(api.Session("smoke").engine, engine_lib.MobyEngine)
         assert isinstance(
@@ -91,10 +83,10 @@ class TestPolicies:
                                 "transform"] * 3
         assert rep.policy == "periodic(4)"
 
-    def test_policy_threads_through_fleet_scan(self):
+    def test_policy_threads_through_fleet(self):
         sess = api.Session(api.scenario("smoke", seed=5, n_streams=2,
                                         policy="periodic(3)"))
-        rep = sess.run(9, scan=True)
+        rep = sess.run(9)
         for s in range(2):
             assert rep.kinds(s)[::3] == ["anchor"] * 3
         assert rep.anchor_rate == pytest.approx(1 / 3)

@@ -246,18 +246,19 @@ def _assert_same_step(got, want, msg):
 
 
 class TestFleetParity:
-    @pytest.mark.parametrize("run_mode", ["run", "run_scan"])
-    def test_s2_fleet_identical(self, run_mode):
+    @pytest.mark.parametrize("mode", ["moby", "moby_onboard"])
+    def test_s2_fleet_identical(self, mode):
         """An S>1 fleet slice must make the same frame-treatment decisions
-        and reach the same F1 under either backend (orchestrated + scan)."""
+        and reach the same F1 under either backend, with cloud or on-board
+        anchors."""
         cfg = _cfg()
         tapes = tape_lib.record_fleet_tapes(cfg, "pointpillar", FRAMES, 2,
                                             seed=5)
 
         def run(backend):
             eng = FleetEngine(cfg, "pointpillar", n_streams=2, seed=5,
-                              tapes=tapes, backend=backend)
-            return getattr(eng, run_mode)(FRAMES)
+                              tapes=tapes, backend=backend, mode=mode)
+            return eng.run(FRAMES)
 
         ref = run("ref")
         pal = run("pallas")

@@ -43,7 +43,7 @@ def test_kitti_phases_agree_with_cpu_reference(smoke):
     _, served, problems = smoke.serve(_tiny(backend="pallas"), FRAMES,
                                       "A/pallas-interpret")
     assert problems == []
-    assert set(served) == {"run", "scan"}
+    assert set(served) == {"run"}
     assert served["run"].kind.shape == (2, FRAMES)
     assert smoke.reference_phase(served, _tiny(backend="ref"), FRAMES,
                                  jax.devices("cpu")[0]) == []

@@ -1,4 +1,4 @@
-"""Multi-GPU cloud batcher tests (fleet.cloud + the scan-mode twin).
+"""Multi-GPU cloud batcher tests (fleet.cloud and the fleet engine).
 
 * **G=1 parity** — the pooled batcher with one GPU reproduces the
   single-server queue exactly (reference reimplementation + the PR 1
@@ -6,8 +6,9 @@
 * **Conservation** — summed per-GPU busy time equals the total dispatched
   service time, for any G / round pattern.
 * **Monotonicity** — anchor latency on the congested fleet preset is
-  non-increasing in the pool size, in both engine modes.
-* **Batch window** — a configured window closes batches early.
+  non-increasing in the pool size.
+* **Batch window** — a configured window closes batches early, and never
+  splits a fleet round, whose requests arrive at one modeled instant.
 """
 import os
 
@@ -124,25 +125,23 @@ class TestPool:
         with pytest.raises(ValueError, match="n_gpus"):
             CloudBatcher(CloudBatcherConfig(infer_s=0.1, n_gpus=0))
 
-    def test_scan_mode_honors_batch_window(self):
-        """The scan twin now mirrors the batch window: a fleet round's
-        requests arrive at one modeled instant, so a window never splits a
-        round and scan mode agrees with the host batcher — the windowed
-        scan run matches the window-free one bitwise, exactly like
-        CloudBatcher on simultaneous arrivals."""
-        def scan_report(cloud):
+    def test_window_never_splits_a_fleet_round(self):
+        """A fleet round's requests arrive at one modeled instant, so a
+        window never splits a round: the windowed run matches the
+        window-free one bitwise, exactly like CloudBatcher on simultaneous
+        arrivals."""
+        def report(cloud):
             sess = api.Session(api.scenario("smoke", n_streams=2, seed=0,
                                             cloud=cloud))
-            r = sess.run(4, scan=True)
+            r = sess.run(4)
             return np.stack([r.latency_s, r.onboard_s, r.f1])
 
         base = CloudBatcherConfig()
-        a = scan_report(cloud_lib.replace_config(base, window_s=0.05))
-        b = scan_report(base)
+        a = report(cloud_lib.replace_config(base, window_s=0.05))
+        b = report(base)
         assert np.array_equal(a, b)
         # ... and the host batcher itself treats simultaneous arrivals
-        # identically with and without the window (the agreement contract
-        # the scan approximation leans on).
+        # identically with and without the window.
         cfg = CloudBatcherConfig(infer_s=0.1, marginal=0.0, max_batch=8)
         d_win = CloudBatcher(
             cloud_lib.replace_config(cfg, window_s=0.05)
@@ -174,8 +173,8 @@ class TestBatchWindow:
 class TestEngineMonotonicity:
     @pytest.fixture(scope="class")
     def congested_reports(self):
-        """fleet-16-congested at G in {1, 2, 4}, both engine modes (tiny
-        frame budget; one compile per mode — the G sweep reuses shapes).
+        """fleet-16-congested at G in {1, 2, 4} (tiny frame budget; one
+        compile — the G sweep reuses shapes).
         max_batch=4 makes the S=16 anchor storm span 4 chunks, so the
         cloud queue — not just the uplink — actually binds."""
         frames = 8
@@ -185,7 +184,7 @@ class TestEngineMonotonicity:
                                cloud=CloudBatcherConfig(n_gpus=g,
                                                         max_batch=4))
             sess = api.Session(scn)
-            out[g] = (sess.run(frames), sess.run(frames, scan=True))
+            out[g] = sess.run(frames)
         return out
 
     def test_engine_fills_infer_s(self, congested_reports):
@@ -195,33 +194,25 @@ class TestEngineMonotonicity:
         assert eng.cloud_cfg.infer_s is not None
         assert eng.cloud_cfg.n_gpus == 2
 
-    @pytest.mark.parametrize("mode", [0, 1], ids=["run", "run_scan"])
-    def test_anchor_latency_non_increasing_in_g(self, congested_reports,
-                                                mode):
-        lats = [congested_reports[g][mode].mean_anchor_latency
+    def test_anchor_latency_non_increasing_in_g(self, congested_reports):
+        lats = [congested_reports[g].mean_anchor_latency
                 for g in (1, 2, 4)]
         assert all(a >= b - 1e-9 for a, b in zip(lats, lats[1:])), lats
         # The congested S=16 cell actually queues: G=4 is a strict win.
         assert lats[2] < lats[0]
 
-    def test_scan_pool_engages_on_single_chunk_rounds(self):
+    def test_pool_engages_on_single_chunk_rounds(self):
         """Consecutive one-chunk rounds must still spread over the pool —
-        the scan twin's round-robin pointer persists across rounds (like
-        CloudBatcher._rr), so a slow detector's back-to-back anchor
-        rounds queue on G=1 and parallelize on G=4, tracking the
-        orchestrated batcher."""
+        the batcher's round-robin pointer persists across rounds
+        (CloudBatcher._rr), so a slow detector's back-to-back anchor
+        rounds queue on G=1 and parallelize on G=4."""
         lats = {}
         for g in (1, 4):
             sess = api.Session(api.scenario(
                 "smoke", n_streams=4, seed=0, policy="periodic(2)",
                 cloud=CloudBatcherConfig(infer_s=0.35, n_gpus=g)))
-            lats[g] = (sess.run(16, scan=True).mean_anchor_latency,
-                       sess.run(16).mean_anchor_latency)
-        scan1, run1 = lats[1]
-        scan4, run4 = lats[4]
-        assert scan4 < 0.6 * scan1          # pool relief is real in scan
-        assert scan1 == pytest.approx(run1, rel=0.05)
-        assert scan4 == pytest.approx(run4, rel=0.05)
+            lats[g] = sess.run(16).mean_anchor_latency
+        assert lats[4] < 0.6 * lats[1]
 
 
 if __name__ == "__main__":

@@ -36,15 +36,34 @@ def shared_tape():
     return tape_lib.record_stream_tape(_cfg(), "pointpillar", FRAMES, seed=5)
 
 
+# Engine settings both engines take: the defaults, on-board anchors, the
+# scheduler and/or tracking-based association off, and three other
+# scheduler policies.
+S1_CASES = {
+    "default": {},
+    "moby_onboard": dict(mode="moby_onboard"),
+    "no_fos": dict(use_fos=False),
+    "no_tba": dict(use_tba=False),
+    "no_fos_no_tba": dict(use_fos=False, use_tba=False),
+    "adaptive": dict(sparams=scheduler.SchedulerParams(policy="adaptive")),
+    "periodic3": dict(
+        sparams=scheduler.SchedulerParams(policy="periodic(3)")),
+    "always_anchor": dict(
+        sparams=scheduler.SchedulerParams(policy="always_anchor")),
+}
+
+
 class TestFleetParity:
-    def test_s1_matches_moby_engine(self, shared_tape):
+    @pytest.mark.parametrize("case", list(S1_CASES))
+    def test_s1_matches_moby_engine(self, shared_tape, case):
         """Same tape through both engines: identical frame treatments and
         outputs — the vmap/lax.cond path is equivalent to host branching."""
         cfg = _cfg()
+        kw = S1_CASES[case]
         moby = engine_lib.MobyEngine(cfg, "pointpillar", seed=5,
-                                     tape=shared_tape).run(FRAMES)
+                                     tape=shared_tape, **kw).run(FRAMES)
         fleet = FleetEngine(cfg, "pointpillar", n_streams=1, seed=5,
-                            tapes=[shared_tape])
+                            tapes=[shared_tape], **kw)
         fr = fleet.run(FRAMES)
 
         assert [r.kind for r in moby.records] == fr.kinds(0)
@@ -56,18 +75,6 @@ class TestFleetParity:
         # batch size is 1, so timing also reduces to the seed engine.
         np.testing.assert_allclose(
             [r.latency_s for r in moby.records], fr.latency_s[0], atol=1e-6)
-
-    def test_scan_matches_orchestrated_decisions(self, shared_tape):
-        """Benchmark mode (one lax.scan dispatch, on-device time model)
-        takes the same anchor/test decisions and produces the same
-        accuracy as the per-frame orchestrated mode."""
-        cfg = _cfg()
-        fleet = FleetEngine(cfg, "pointpillar", n_streams=1, seed=5,
-                            tapes=[shared_tape])
-        orch = fleet.run(FRAMES)
-        scan = fleet.run_scan(FRAMES)
-        assert orch.kinds(0) == scan.kinds(0)
-        np.testing.assert_allclose(orch.f1[0], scan.f1[0], atol=1e-5)
 
     def test_multi_stream_runs_and_anchors(self):
         cfg = _cfg()

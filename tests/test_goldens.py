@@ -33,41 +33,29 @@ jax.config.update("jax_platform_name", "cpu")
 GOLDEN_DIR = pathlib.Path(__file__).parent / "goldens"
 DIFF_DIR = pathlib.Path(os.environ.get("GOLDEN_DIFF_DIR", "golden-diff"))
 
-# (preset, frames, ops backend, scan mode): small enough to diff by eye,
-# long enough to cross the first test/anchor cycles of every stream.
-# fleet-64-mixed exercises the heterogeneous-device path; the pallas leg
-# guards the kernel backend's serving numbers (interpret mode on CPU).
-# The fleet-256-congested scan-mode leg pins the one-dispatch lax.scan
-# path (on-device net/cloud model) at megafleet scale; its runtime is
-# minutes on a 1-core host, so it only runs when MOBY_SLOW_GOLDENS=1 is
-# set (the CI tier-1 leg sets it; plain local runs skip).
-GOLDENS = (("smoke", 16, "ref", False),
-           ("fleet-16-congested", 8, "ref", False),
-           ("fleet-64-mixed", 6, "ref", False),
-           ("smoke", 16, "pallas", False),
-           ("fleet-256-congested", 4, "ref", True))
+# (preset, frames, ops backend): small enough to diff by eye, long enough
+# to cross the first test/anchor cycles of every stream. fleet-64-mixed
+# exercises the heterogeneous-device path; the pallas leg guards the
+# kernel backend's serving numbers (interpret mode on CPU).
+GOLDENS = (("smoke", 16, "ref"),
+           ("fleet-16-congested", 8, "ref"),
+           ("fleet-64-mixed", 6, "ref"),
+           ("smoke", 16, "pallas"))
 
 _EXACT = ("stream", "frame", "kind", "scenario", "policy", "device")
 _FLOAT = ("latency_s", "onboard_s", "f1", "precision", "recall")
 
 
-def _golden_name(preset: str, backend: str, scan: bool = False) -> str:
-    """ref goldens keep their pre-backend-matrix filenames; scan-mode
-    goldens carry a ``-scan`` suffix."""
+def _golden_name(preset: str, backend: str) -> str:
+    """ref goldens keep their pre-backend-matrix filenames."""
     stem = preset if backend == "ref" else f"{preset}-{backend}"
-    return f"{stem}-scan.csv" if scan else f"{stem}.csv"
+    return f"{stem}.csv"
 
 
-def _slow_gated(preset: str, scan: bool) -> bool:
-    """Scan-mode fleet goldens are minutes of wall time: opt-in via env."""
-    return scan and not os.environ.get("MOBY_SLOW_GOLDENS")
-
-
-def _generate(preset: str, frames: int, backend: str = "ref",
-              scan: bool = False) -> str:
+def _generate(preset: str, frames: int, backend: str = "ref") -> str:
     """The golden contract: seed 0, pinned ops backend, preset defaults."""
     scn = api.scenario(preset, seed=0, backend=backend)
-    return api.Session(scn).run(frames, scan=scan).to_csv()
+    return api.Session(scn).run(frames).to_csv()
 
 
 def _rows(text: str):
@@ -75,13 +63,11 @@ def _rows(text: str):
 
 
 @pytest.mark.parametrize(
-    "preset,frames,backend,scan", GOLDENS,
-    ids=[f"{g[0]}-{g[2]}{'-scan' if g[3] else ''}" for g in GOLDENS])
-def test_matches_golden(preset, frames, backend, scan):
-    if _slow_gated(preset, scan):
-        pytest.skip("scan-mode golden: set MOBY_SLOW_GOLDENS=1 to run")
-    path = GOLDEN_DIR / _golden_name(preset, backend, scan)
-    text = _generate(preset, frames, backend, scan)
+    "preset,frames,backend", GOLDENS,
+    ids=[f"{g[0]}-{g[2]}" for g in GOLDENS])
+def test_matches_golden(preset, frames, backend):
+    path = GOLDEN_DIR / _golden_name(preset, backend)
+    text = _generate(preset, frames, backend)
     if os.environ.get("MOBY_REGEN_GOLDENS"):
         GOLDEN_DIR.mkdir(exist_ok=True)
         path.write_text(text)
@@ -104,15 +90,15 @@ def test_matches_golden(preset, frames, backend, scan):
     except AssertionError:
         # Leave the regenerated CSV behind for review (CI uploads it).
         DIFF_DIR.mkdir(exist_ok=True)
-        (DIFF_DIR / _golden_name(preset, backend, scan)).write_text(text)
+        (DIFF_DIR / _golden_name(preset, backend)).write_text(text)
         raise
 
 
 def test_golden_covers_interesting_kinds():
     """The fixtures would not guard the scheduler if they only ever saw
-    transform frames. (Checked-in CSVs, so gated goldens verify too.)"""
-    for preset, _, backend, scan in GOLDENS:
-        path = GOLDEN_DIR / _golden_name(preset, backend, scan)
+    transform frames."""
+    for preset, _, backend in GOLDENS:
+        path = GOLDEN_DIR / _golden_name(preset, backend)
         kinds = {r["kind"] for r in _rows(path.read_text())}
         assert "anchor" in kinds and "transform" in kinds, (preset, kinds)
 

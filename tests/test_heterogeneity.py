@@ -4,13 +4,14 @@ Pillars of the ProfileVector layer (runtime.profiles -> fleet.engine):
 
 * **Uniform-mix parity** — a fleet whose per-stream profile vector is a
   uniform broadcast of device D is *bitwise identical* to the scalar
-  ``device=D`` path, for both the orchestrated ``run`` and the
-  single-dispatch ``run_scan`` (the acceptance invariant guarding the
+  ``device=D`` path (the acceptance invariant guarding the
   scalar->vector refactor), and the S=1 fleet still reproduces the
   (scalar-only) MobyEngine.
 * **Permutation equivariance** — relabeling streams (tape, device and
   PRNG seed together) permutes the per-stream outputs and leaves fleet
-  aggregates unchanged: no hidden dependence on stream order.
+  aggregates unchanged, under the paper's ``fos`` policy and under the
+  telemetry-driven ``adaptive`` one: no hidden dependence on stream
+  order.
 * **Model equivalence properties** (hypothesis, via hypothesis_compat) —
   the vectorized component/latency models agree elementwise with the
   scalar ones for any registered device and any mix spec.
@@ -24,8 +25,9 @@ import pytest
 
 from hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
 
+from repro.core import scheduler
 from repro.data import scenes
-from repro.fleet import FleetEngine, step as step_lib
+from repro.fleet import FleetEngine
 from repro.runtime import profiles
 from repro.serving import engine as engine_lib
 from repro.serving import tape as tape_lib
@@ -59,8 +61,7 @@ def _assert_reports_equal(a, b, msg=""):
 class TestUniformMixParity:
     @pytest.mark.parametrize("device", EDGE_DEVICES)
     def test_uniform_vector_is_bitwise_scalar(self, device):
-        """device=D == device=[D]*S == device={D: 1.0}, run and run_scan,
-        bitwise."""
+        """device=D == device=[D]*S == device={D: 1.0}, bitwise."""
         cfg = _cfg()
         reports = {}
         for label, spec in (("scalar", device),
@@ -68,13 +69,11 @@ class TestUniformMixParity:
                             ("mix", {device: 1.0})):
             eng = FleetEngine(cfg, "pointpillar", n_streams=2, seed=5,
                               device=spec)
-            reports[label] = (eng.run(FRAMES), eng.run_scan(FRAMES))
+            reports[label] = eng.run(FRAMES)
         for label in ("list", "mix"):
-            for i, mode in enumerate(("run", "run_scan")):
-                _assert_reports_equal(reports["scalar"][i],
-                                      reports[label][i],
-                                      f"{device}/{label}/{mode}")
-                assert list(reports[label][i].device) == [device] * 2
+            _assert_reports_equal(reports["scalar"], reports[label],
+                                  f"{device}/{label}")
+            assert list(reports[label].device) == [device] * 2
 
     @pytest.mark.parametrize("device", EDGE_DEVICES)
     def test_s1_vector_fleet_matches_scalar_moby_engine(self, device):
@@ -98,29 +97,28 @@ class TestUniformMixParity:
 
 
 class TestPermutationEquivariance:
-    @pytest.fixture(scope="class")
-    def fleet_runs(self):
-        """A mixed S=3 fleet and its relabeling under a permutation."""
+    @pytest.fixture(scope="class", params=["fos", "adaptive"])
+    def fleet_runs(self, request):
+        """A mixed S=3 fleet and its relabeling under a permutation, served
+        under the policy ``request.param``."""
         cfg = _cfg()
         s_n = 3
         devices = ["jetson_tx2", "jetson_orin", "jetson_tx2"]
         tapes = tape_lib.record_fleet_tapes(cfg, "pointpillar", FRAMES, s_n,
                                             seed=5)
         perm = [2, 0, 1]
+        sparams = scheduler.SchedulerParams(policy=request.param)
         base = FleetEngine(cfg, "pointpillar", n_streams=s_n, seed=5,
-                           tapes=tapes, device=devices,
+                           tapes=tapes, device=devices, sparams=sparams,
                            stream_seeds=list(range(s_n)))
         permuted = FleetEngine(cfg, "pointpillar", n_streams=s_n, seed=5,
                                tapes=[tapes[p] for p in perm],
                                device=[devices[p] for p in perm],
-                               stream_seeds=perm)
-        return perm, (base.run(FRAMES), base.run_scan(FRAMES)), \
-            (permuted.run(FRAMES), permuted.run_scan(FRAMES))
+                               sparams=sparams, stream_seeds=perm)
+        return perm, base.run(FRAMES), permuted.run(FRAMES)
 
-    @pytest.mark.parametrize("mode", [0, 1], ids=["run", "run_scan"])
-    def test_outputs_permute(self, fleet_runs, mode):
-        perm, base, permuted = fleet_runs
-        a, b = base[mode], permuted[mode]
+    def test_outputs_permute(self, fleet_runs):
+        perm, a, b = fleet_runs
         assert (b.kind == a.kind[perm]).all()
         assert list(b.device) == [a.device[p] for p in perm]
         for col in ("latency_s", "onboard_s", "f1", "precision", "recall"):
@@ -128,10 +126,8 @@ class TestPermutationEquivariance:
                 getattr(b, col), getattr(a, col)[perm], atol=1e-6,
                 err_msg=col)
 
-    @pytest.mark.parametrize("mode", [0, 1], ids=["run", "run_scan"])
-    def test_aggregates_invariant(self, fleet_runs, mode):
-        _, base, permuted = fleet_runs
-        a, b = base[mode], permuted[mode]
+    def test_aggregates_invariant(self, fleet_runs):
+        _, a, b = fleet_runs
         assert b.mean_latency == pytest.approx(a.mean_latency, abs=1e-7)
         assert b.mean_f1 == pytest.approx(a.mean_f1, abs=1e-6)
         assert b.anchor_rate == a.anchor_rate
@@ -198,8 +194,7 @@ class TestMixedFleetSweep:
         from benchmarks import sweep as sweep_mod
 
         text, _ = sweep_mod.sweep(scenarios=("fleet-64-mixed",),
-                                  policies=("adaptive",), frames=24,
-                                  scan=True)
+                                  policies=("adaptive",), frames=24)
         per_stream = {}
         for r in csv.DictReader(io.StringIO(text)):
             per_stream.setdefault((r["device"], r["stream"]),
@@ -255,29 +250,6 @@ def test_permuting_device_list_permutes_models(devices, seed):
     cost_p = nominal_transform_time(profiles.component_times_vector(pv_p),
                                     use_tba=True, use_fos=True)
     np.testing.assert_array_equal(cost_p, np.asarray(cost)[perm])
-
-
-@settings(max_examples=20, deadline=None)
-@given(device=_DEVICE_ST,
-       n_assoc=st.integers(min_value=0, max_value=12),
-       n_new=st.integers(min_value=0, max_value=12),
-       use_tba=st.booleans(), use_fos=st.booleans())
-def test_onboard_time_vec_matches_host_model(device, n_assoc, n_new,
-                                             use_tba, use_fos):
-    """The traceable (S,)-broadcast on-board model equals the host-side
-    scalar model on every device (f32 rounding of the same f64 values)."""
-    import jax.numpy as jnp
-
-    from repro.serving.common import onboard_transform_time
-
-    pv = profiles.profile_vector(device, 2)
-    ct = profiles.component_times_vector(pv)
-    vec = step_lib.onboard_time_vec(
-        ct, jnp.asarray([float(n_assoc)] * 2),
-        jnp.asarray([float(n_new)] * 2), use_tba, use_fos)
-    scalar = onboard_transform_time(profiles.component_slice(ct, 0),
-                                    n_assoc, n_new, use_tba, use_fos)
-    np.testing.assert_allclose(np.asarray(vec), scalar, rtol=1e-6)
 
 
 if __name__ == "__main__":

@@ -14,8 +14,7 @@ Contract under test:
   to the pool's ``busy_s_g`` accounting, stream lanes reconstructable
   with no observer attached;
 * **audit** — exactly one decision row per stream-frame carrying every
-  policy input, JSONL/CSV export, and the scan-mode refusal (audit needs
-  the orchestrated loop).
+  policy input, and JSONL/CSV export.
 """
 import json
 import re
@@ -251,24 +250,6 @@ class TestAudit:
         rep = api.Session(api.scenario("smoke", seed=0)).run(4)
         with pytest.raises(ValueError, match="audit"):
             rep.to_audit()
-
-    def test_scan_mode_refuses_audit(self):
-        sess = api.Session(api.scenario("smoke", seed=0, n_streams=2),
-                           obs=obs.ObsConfig(audit=True))
-        with pytest.raises(ValueError, match="scan"):
-            sess.run(4, scan=True)
-
-    def test_scan_mode_metrics_and_trace_work(self):
-        cfg = obs.ObsConfig(metrics=True, trace=True,
-                            registry=obs.MetricsRegistry())
-        sess = api.Session(api.scenario("smoke", seed=0, n_streams=2),
-                           obs=cfg)
-        rep = sess.run(4, scan=True)
-        assert rep.obs is not None
-        spans = [e for e in rep.to_trace()["traceEvents"]
-                 if e["ph"] == "X" and e["pid"] == trace_lib.PID_STREAMS]
-        assert len(spans) == rep.n_streams * rep.n_frames
-        assert "moby_frames_total" in rep.metrics_registry().names()
 
 
 # ---------------------------------------------------------------------------

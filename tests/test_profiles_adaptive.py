@@ -260,13 +260,12 @@ class TestAdaptivePolicy:
                                        backend="pallas")).run(8)
         assert ref.kinds(0) == pal.kinds(0)
 
-    def test_threads_through_fleet_scan(self):
-        sess = api.Session(api.scenario("smoke", seed=5, n_streams=2,
-                                        policy="adaptive"))
-        orch = sess.run(8)
-        scan = sess.run(8, scan=True)
-        assert orch.kinds(0)[0] == "anchor"
-        assert scan.n_streams == 2 and scan.n_frames == 8
+    def test_threads_through_fleet(self):
+        rep = api.Session(api.scenario("smoke", seed=5, n_streams=2,
+                                       policy="adaptive")).run(8)
+        assert rep.n_streams == 2 and rep.n_frames == 8
+        assert [rep.kinds(s)[0] for s in range(2)] == ["anchor"] * 2
+        assert rep.policy == "adaptive"
 
 
 class TestSweep:

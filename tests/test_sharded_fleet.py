@@ -2,18 +2,18 @@
 FleetEngine / Session.
 
 * **1-device-mesh parity** — ``mesh=1`` must reproduce the unsharded path
-  BITWISE in both run modes and on both ops backends: the sharded code
-  path (NamedSharding boundaries, shard_map + psum scan, donated carries)
-  is a pure partitioning of the same math.
-* **Donation** — the compiled HLO of both dispatches aliases every carry
-  leaf (``input_output_alias``), so device memory stays flat in run
-  length and fleet size (runtime.hlo_analysis).
+  BITWISE on both ops backends: the sharded code path (NamedSharding
+  boundaries, shard_map, donated carries) is a pure partitioning of the
+  same math.
+* **Donation** — the compiled HLO of the step aliases every carry leaf
+  (``input_output_alias``), so device memory stays flat in run length
+  and fleet size (runtime.hlo_analysis).
 * **Multi-device parity** — a subprocess with 4 virtual CPU devices
   (``--xla_force_host_platform_device_count`` must precede JAX init)
-  checks sharded == unsharded bitwise with real cross-shard psum, and
-  that an observed orchestrated run puts each round's inputs over the
-  four chips (``fleet/inputs``' ``chips`` and ``bytes_per_chip``, the
-  per-shard input-bytes counter) without a retrace.
+  checks sharded == unsharded bitwise, and that an observed run puts
+  each round's inputs over the four chips (``fleet/inputs``' ``chips``
+  and ``bytes_per_chip``, the per-shard input-bytes counter) without a
+  retrace.
 * **API routing** — Scenario.mesh reaches the engine; "auto" degrades to
   the unsharded path on a 1-device host; per-shard observer metrics.
 """
@@ -64,13 +64,6 @@ class TestMesh1Parity:
         assert np.array_equal(_packed(a), _packed(b))
         assert np.array_equal(a.kind, b.kind)
 
-    @pytest.mark.parametrize("backend", ["ref", "pallas"])
-    def test_run_scan_bitwise(self, backend):
-        a = _engine(None, backend).run_scan(FRAMES)
-        b = _engine(1, backend).run_scan(FRAMES)
-        assert np.array_equal(_packed(a), _packed(b))
-        assert np.array_equal(a.kind, b.kind)
-
 
 class TestDonation:
     def _step_hlo(self, mesh):
@@ -90,15 +83,6 @@ class TestDonation:
             assert len(donated) >= n_carry, (mesh, sorted(donated))
             assert all(i < n_carry for i in donated)
 
-    def test_scan_donates_carry(self):
-        e = _engine(1)
-        st = e._init_state()
-        fn, consts = e._scan_fn()
-        hlo = fn.lower(consts, st, e._scan_inputs(4), 4).compile().as_text()
-        donated = hlo_analysis.donated_params(hlo)
-        n_carry = len(jax.tree.leaves(st))
-        assert len(donated) >= n_carry, sorted(donated)
-
     def test_alias_parser_roundtrip(self):
         f = jax.jit(lambda x, y: (x * 2, y + x), donate_argnums=(1,))
         txt = f.lower(jnp.zeros((4,)), jnp.zeros((4,))).compile().as_text()
@@ -109,7 +93,7 @@ class TestDonation:
 class TestMultiDevice:
     def test_sharded_matches_unsharded_bitwise(self):
         """Real 4-shard run (virtual devices) vs the unsharded path: the
-        psum-coupled contention model must make them identical. The flag
+        host-global contention model must make them identical. The flag
         only takes effect before JAX initializes — hence a subprocess."""
         prog = textwrap.dedent("""
             import numpy as np, jax
@@ -122,7 +106,7 @@ class TestMultiDevice:
             def rep(mesh):
                 e = FleetEngine(cfg, "pointpillar", n_streams=8, seed=0,
                                 mesh=mesh)
-                r = e.run_scan(5)
+                r = e.run(5)
                 return (np.stack([r.latency_s, r.onboard_s, r.f1,
                                   r.precision, r.recall]), r.kind)
             a, ka = rep(None)
@@ -131,9 +115,9 @@ class TestMultiDevice:
             assert np.array_equal(ka, kb)
             print("SHARDED_PARITY_OK")
 
-            # Observed orchestrated rounds: every put of a round is
-            # split over the four chips, and the per-shard counter adds
-            # up to what the rounds put.
+            # Observed rounds: every put of a round is split over the
+            # four chips, and the per-shard counter adds up to what the
+            # rounds put.
             from repro.obs import MetricsRegistry, ObsConfig
             reg = MetricsRegistry()
             e = FleetEngine(cfg, "pointpillar", n_streams=8, seed=0, mesh=4,
@@ -203,8 +187,8 @@ class TestApiRouting:
                            n_points=512, img_h=32, img_w=104)
         base = api.scenario("smoke", n_streams=2,
                             n_points=512, img_h=32, img_w=104)
-        a = api.Session(scn).run(4, scan=True)
-        b = api.Session(base).run(4, scan=True)
+        a = api.Session(scn).run(4)
+        b = api.Session(base).run(4)
         assert np.array_equal(_packed(a), _packed(b))
 
     def test_shard_metrics_labels(self):
